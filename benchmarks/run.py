@@ -109,7 +109,8 @@ def run_all(min_time: float = 0.02):
     harness can't silently miss a scope the binary knows about.
     """
     from repro.core import REGISTRY, RunOptions, parse_param_filter
-    from repro.core.orchestrate import OrchestratorOptions, execute
+    from repro.core.orchestrate import (OrchestratorOptions,
+                                        chip_sharing_error, execute)
     from repro.core.scope import ScopeManager
 
     jobs = int(os.environ.get("BENCH_JOBS", "1"))
@@ -119,17 +120,21 @@ def run_all(min_time: float = 0.02):
     except ValueError as e:
         import sys
         sys.exit(f"BENCH_PARAM: {e}")
-    REGISTRY.reset()
-    mgr = ScopeManager()
-    mgr.load(None)                       # BUILTIN_SCOPES — the Table IV set
-    mgr.register_all()
-    scope_names = [s.scope.name for s in mgr.scopes()]
     opts = OrchestratorOptions(
         jobs=jobs,
         shard_grain=os.environ.get("BENCH_SHARD_GRAIN", "auto"),
         run=RunOptions(min_time=min_time, param_filter=param_filter),
         results_dir=os.environ.get("BENCH_RESULTS_DIR"),
     )
+    refusal = chip_sharing_error(opts)
+    if refusal:
+        import sys
+        sys.exit(f"BENCH_JOBS: {refusal}")
+    REGISTRY.reset()
+    mgr = ScopeManager()
+    mgr.load(None)                       # BUILTIN_SCOPES — the Table IV set
+    mgr.register_all()
+    scope_names = [s.scope.name for s in mgr.scopes()]
     result = execute(mgr, REGISTRY, opts,
                      context_extra={"scopes": mgr.status()})
     unavailable = {s.scope.name: s.error for s in mgr.scopes()

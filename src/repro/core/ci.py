@@ -36,7 +36,7 @@ from .benchmark import parse_param_filter
 from .cli_examples import epilog
 from .flags import FLAGS
 from .history import DEFAULT_WINDOW, detect_drift, history_path, load_history
-from .orchestrate import OK, OrchestratorOptions, execute
+from .orchestrate import OK, OrchestratorOptions, chip_sharing_error, execute
 from .registry import REGISTRY
 from .runner import RunOptions
 
@@ -100,6 +100,10 @@ def ci_main(argv: List[str],
     if not ns.results_dir:
         log.error("repro ci needs a --results-dir (history is both the "
                   "freshness source and the drift baseline)")
+        return 2
+    refusal = chip_sharing_error(OrchestratorOptions(jobs=ns.jobs))
+    if refusal:
+        log.error("%s", refusal)
         return 2
 
     from .main import _delta_cached, _setup_scopes

@@ -48,7 +48,8 @@ from .cli_examples import epilog
 from .flags import FLAGS
 from .hooks import HOOKS
 from .measure import parse_meters
-from .orchestrate import OrchestratorOptions, execute
+from .orchestrate import (OrchestratorOptions, chip_sharing_error, execute,
+                          failed_instances)
 from .plan import build_plan, load_cost_hints, scope_worklist
 from .registry import REGISTRY
 from .runner import RunOptions, write_json
@@ -312,6 +313,11 @@ def run_main(argv: List[str],
         log.error("--since requires benchmark shard grain "
                   "(drop --shard-grain scope)")
         return 2
+    refusal = chip_sharing_error(OrchestratorOptions(jobs=sel_ns.jobs,
+                                                     isolate=sel_ns.isolate))
+    if refusal:
+        log.error("%s", refusal)
+        return 2
 
     # load the baseline up front: a bad path must fail before the run,
     # and a history.jsonl baseline must be snapshotted before this run
@@ -416,6 +422,11 @@ def run_main(argv: List[str],
                  result.run_id)
 
     rc = 0
+    failed = failed_instances(doc)
+    if failed:
+        log.error("%d instance(s) ended error or crashed: %s", len(failed),
+                  ", ".join(failed[:8]))
+        rc = 1
     if base_doc is not None:
         comps = compare_documents(base_doc, doc)
         print(format_comparisons(comps), file=sys.stderr)

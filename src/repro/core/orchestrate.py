@@ -85,6 +85,39 @@ PARTIAL = "partial"  # scope rollup: some instances ok, some not
 EXTERNAL = "<external>"   # module marker for add_scope()-registered scopes
 
 
+def failed_instances(doc: Dict[str, Any]) -> List[str]:
+    """Names of the instances in a merged document that ended ``error``
+    or ``crashed``: every one of their records carries
+    ``error_occurred`` (a failed scope contributes one such record).
+    Skipped instances have not failed."""
+    errored: Dict[str, bool] = {}
+    for rec in doc.get("benchmarks", []):
+        if rec.get("run_type") == "aggregate":
+            continue
+        name = rec.get("run_name") or rec.get("name", "")
+        errored[name] = errored.get(name, True) \
+            and bool(rec.get("error_occurred"))
+    return [name for name, failed in errored.items() if failed]
+
+
+def chip_sharing_error(opts: "OrchestratorOptions") -> Optional[str]:
+    """Why ``opts`` may not run here, or None.
+
+    Every mode but ``inline`` starts worker processes, and each of them
+    initializes JAX.  An accelerator chip belongs to one process at a
+    time, so workers that can reach one would fail or hang on it.  They
+    are allowed only when ``JAX_PLATFORMS=cpu``, which they inherit, pins
+    them to the CPU — a check that touches no device.
+    """
+    if opts.mode() == "inline" or \
+            os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
+        return None
+    return (f"--jobs {opts.jobs} (isolation {opts.mode()}) starts worker "
+            f"processes that each initialize JAX, and a chip belongs to "
+            f"one process at a time: run with --jobs 1, or pin the "
+            f"workers to the CPU with JAX_PLATFORMS=cpu")
+
+
 def _spawn_safe_main() -> bool:
     main = sys.modules.get("__main__")
     if getattr(main, "__spec__", None) is not None:   # python -m …
@@ -937,8 +970,13 @@ def execute(mgr, registry, opts: OrchestratorOptions,
     ``opts.grain()`` picks the schedulable unit: benchmark instances
     (:func:`_execute_plan_grain`) or whole scopes.  External scopes
     (added with ``add_scope``, no importable module) always run inline —
-    a worker cannot re-import them.
+    a worker cannot re-import them.  Raises ``ValueError`` before any
+    work when the schedule would share a chip between processes
+    (:func:`chip_sharing_error`).
     """
+    refusal = chip_sharing_error(opts)
+    if refusal:
+        raise ValueError(refusal)
     if opts.grain() == "benchmark":
         return _execute_plan_grain(mgr, registry, opts, context_extra)
     if opts.resume:

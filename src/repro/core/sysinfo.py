@@ -15,21 +15,49 @@ import json
 import os
 import platform
 import types
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Mapping, Optional
 
-# Target-hardware constants (TPU v5e) used by the modeled scopes & roofline.
-# Immutable on purpose: benchmark bodies read these at call time, and the
-# instance fingerprint (repro.core.fingerprint) only hashes source — a
-# mutable table here could change measurements without changing digests
-# (the SCOPE110 hazard).
+# Published per-chip peaks, keyed by ``device_kind`` as JAX reports it.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819
+# GB/s HBM).  Immutable on purpose: benchmark bodies read these at call
+# time, and the instance fingerprint (repro.core.fingerprint) only hashes
+# source — a mutable table here could change measurements without
+# changing digests (the SCOPE110 hazard).
+DEVICE_PEAKS = types.MappingProxyType({
+    "TPU v5 lite": types.MappingProxyType({
+        "peak_bf16_flops": 197e12,     # FLOP/s per chip
+        "hbm_bandwidth": 819e9,        # B/s per chip
+    }),
+})
+
+
+def device_peaks(device) -> Optional[Mapping[str, float]]:
+    """The peak rates of the device a measurement ran on.
+
+    ``None`` on the CPU, which has no published roofline: callers write
+    no roofline counter there.  Any other device must be in
+    :data:`DEVICE_PEAKS`; an unknown kind raises instead of borrowing
+    another chip's peaks.
+    """
+    if device.platform == "cpu":
+        return None
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for {device.platform} device kind "
+            f"{device.device_kind!r}: add them, with their source, to "
+            f"repro.core.sysinfo.DEVICE_PEAKS") from None
+
+
+# The modeled target of the paths that model a TPU v5e pod without
+# running on one: the dry-run on placeholder devices
+# (repro.roofline.analysis) and comm/collective_modeled_v5e.
 TPU_V5E = types.MappingProxyType({
-    "name": "tpu_v5e",
-    "peak_bf16_flops": 197e12,     # FLOP/s per chip
-    "hbm_bandwidth": 819e9,        # B/s per chip
+    **DEVICE_PEAKS["TPU v5 lite"],
     "ici_link_bandwidth": 50e9,    # B/s per link (~50 GB/s/link)
     "ici_links_per_chip": 4,       # 2D torus: +x, -x, +y, -y
     "hbm_bytes": 16 * 2 ** 30,     # 16 GiB HBM per chip
-    "vmem_bytes": 128 * 2 ** 20,   # ~128 MiB VMEM per core
     "mxu_shape": (128, 128),       # systolic array tile
     "dcn_bandwidth": 25e9,         # B/s per host cross-pod (modeled)
 })
@@ -72,7 +100,7 @@ def _jax_info() -> Dict[str, Any]:
 _DIGEST_KEYS = (
     "host_name", "machine", "processor", "num_cpus", "model_name",
     "jax_version", "backend", "device_count", "device_kind",
-    "xla_flags", "target_hardware", "scope_version",
+    "xla_flags", "scope_version",
 )
 
 
@@ -100,7 +128,6 @@ def build_context(extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
         **_cpu_info(),
         **_jax_info(),
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
-        "target_hardware": TPU_V5E["name"],
     }
     if extra:
         ctx.update(extra)

@@ -16,12 +16,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro.kernels.tuning import VMEM_LIMIT_BYTES
 
 NEG_INF = -1e30
 
@@ -90,8 +87,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     kernel = functools.partial(_flash_kernel, causal=causal, bq=bq_, bk=bk_,
                                nk=nk, scale=scale)
-    if _VMEM is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU scratch unavailable")
     out = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
@@ -102,9 +97,11 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, bq_, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-        scratch_shapes=[_VMEM((bq_, 1), jnp.float32),
-                        _VMEM((bq_, 1), jnp.float32),
-                        _VMEM((bq_, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq_, 1), jnp.float32),
+                        pltpu.VMEM((bq_, 1), jnp.float32),
+                        pltpu.VMEM((bq_, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(qt, kt, vt)
     return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

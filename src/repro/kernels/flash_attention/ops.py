@@ -5,7 +5,7 @@ jit boundary (kwarg > env > tuned.json > builtin) so tuned defaults and
 tune-trial overrides take effect without retracing stale configs.
 """
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 
@@ -21,11 +21,12 @@ def _flash_attention(q, k, v, causal: bool, bq: int, bk: int):
         interpret=jax.default_backend() != "tpu")
 
 
-def flash_attention(q, k, v, *, causal: bool = True,
-                    bq: Optional[int] = None, bk: Optional[int] = None):
-    """Online-softmax attention; ``bq``/``bk`` default to tuned blocks."""
+def blocks(q, k, *, bq: Optional[int] = None,
+           bk: Optional[int] = None) -> Dict[str, int]:
+    """The validated, shape-clamped blocks :func:`flash_attention` runs
+    with (kwarg > tuned configuration)."""
     cfg = tuning.resolve("flash_attention", bq=bq, bk=bk)
-    Sq, Sk = q.shape[2], k.shape[2]
+    Sq, Sk = q.shape[1], k.shape[1]             # q [B,Sq,H,D], k [B,Sk,K,D]
     D = q.shape[-1]
     eff = {"bq": min(cfg["bq"], Sq), "bk": min(cfg["bk"], Sk)}
     # q block + k/v blocks + the bq x bk scores tile + fp32 acc and the
@@ -36,4 +37,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
                 + eff["bq"] * D * q.dtype.itemsize)
     tuning.validate_blocks("flash_attention", eff,
                            dims={"bq": Sq, "bk": Sk}, vmem_bytes=vmem)
-    return _flash_attention(q, k, v, causal, **eff)
+    return eff
+
+
+def flash_attention(q, k, v, *, causal: bool = True,
+                    bq: Optional[int] = None, bk: Optional[int] = None):
+    """Online-softmax attention; ``bq``/``bk`` default to tuned blocks."""
+    return _flash_attention(q, k, v, causal, **blocks(q, k, bq=bq, bk=bk))

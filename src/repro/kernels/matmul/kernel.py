@@ -4,7 +4,7 @@ Grid (M/bm, N/bn, K/bk); K is the innermost (sequential) grid dim so the
 fp32 VMEM accumulator carries across K steps and spills to HBM exactly once
 per (i, j) tile.  Block sizes default to MXU-aligned 512×512×512 (bf16
 working set = 2·512·512·2B + acc 512·512·4B ≈ 2.1 MiB — far under the
-~128 MiB v5e VMEM so the pipeline can run several tiles in flight).
+scoped-VMEM limit, ``repro.kernels.tuning.VMEM_LIMIT_BYTES``).
 """
 from __future__ import annotations
 
@@ -13,13 +13,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU memory spaces; absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro.kernels.tuning import VMEM_LIMIT_BYTES
 
 
 def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
@@ -47,7 +43,8 @@ def matmul_pallas(x: jax.Array, y: jax.Array, *,
         f"{(M, N, K)} not divisible by {(bm, bn, bk)}"
     nk = K // bk
     out_dtype = out_dtype or x.dtype
-    kwargs = dict(
+    return pl.pallas_call(
+        functools.partial(_matmul_kernel, nk=nk),
         grid=(M // bm, N // bn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -55,11 +52,8 @@ def matmul_pallas(x: jax.Array, y: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )
-    if _VMEM is not None:
-        kwargs["scratch_shapes"] = [_VMEM((bm, bn), jnp.float32)]
-        kernel = functools.partial(_matmul_kernel, nk=nk)
-    else:  # pragma: no cover - CPU installs always ship pltpu
-        raise RuntimeError("pallas TPU scratch unavailable")
-    return pl.pallas_call(kernel, **kwargs)(x, y)
+    )(x, y)

@@ -6,7 +6,7 @@ or a tune-trial override is honoured on the next call rather than being
 frozen into a cached trace keyed on the default.
 """
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 
@@ -25,9 +25,10 @@ def _matmul(x, y, bm: int, bn: int, bk: int):
                          interpret=not _on_tpu())
 
 
-def matmul(x, y, *, bm: Optional[int] = None, bn: Optional[int] = None,
-           bk: Optional[int] = None):
-    """Tiled ``x @ y``; block sizes default to the tuned configuration."""
+def blocks(x, y, *, bm: Optional[int] = None, bn: Optional[int] = None,
+           bk: Optional[int] = None) -> Dict[str, int]:
+    """The validated, shape-clamped blocks :func:`matmul` runs ``x @ y``
+    with (kwarg > tuned configuration)."""
     cfg = tuning.resolve("matmul", bm=bm, bn=bn, bk=bk)
     M, K = x.shape
     N = y.shape[1]
@@ -40,4 +41,10 @@ def matmul(x, y, *, bm: Optional[int] = None, bn: Optional[int] = None,
                 + eff["bm"] * eff["bn"] * (4 + x.dtype.itemsize))
     tuning.validate_blocks("matmul", eff, dims={"bm": M, "bn": N, "bk": K},
                            vmem_bytes=vmem)
-    return _matmul(x, y, **eff)
+    return eff
+
+
+def matmul(x, y, *, bm: Optional[int] = None, bn: Optional[int] = None,
+           bk: Optional[int] = None):
+    """Tiled ``x @ y``; block sizes default to the tuned configuration."""
+    return _matmul(x, y, **blocks(x, y, bm=bm, bn=bn, bk=bk))

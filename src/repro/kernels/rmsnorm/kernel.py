@@ -3,7 +3,7 @@
 Grid (nrows/br,): each step loads a [br, d] tile + the [d] scale into VMEM,
 computes mean-of-squares in fp32 and writes the normalized tile — XLA's
 unfused version reads x twice (square-reduce, then scale).  d up to 8192 at
-br=256 → 256·8192·2B ≈ 4 MiB tiles.
+br=256 → 256·8192·2B ≈ 4 MiB tiles, plus the fp32 working copy.
 """
 from __future__ import annotations
 
@@ -12,6 +12,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tuning import VMEM_LIMIT_BYTES
 
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -36,6 +39,8 @@ def rmsnorm_pallas(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((br_, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(xr, scale)
     return out.reshape(orig_shape)

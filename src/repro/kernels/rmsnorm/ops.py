@@ -4,7 +4,7 @@
 outside the jit boundary (kwarg > env > tuned.json > builtin).
 """
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 
@@ -19,8 +19,9 @@ def _rmsnorm(x, scale, eps: float, br: int):
                           interpret=jax.default_backend() != "tpu")
 
 
-def rmsnorm(x, scale, *, eps: float = 1e-6, br: Optional[int] = None):
-    """Row-blocked RMSNorm; ``br`` defaults to the tuned block size."""
+def blocks(x, scale, *, br: Optional[int] = None) -> Dict[str, int]:
+    """The validated, shape-clamped block :func:`rmsnorm` runs with
+    (kwarg > tuned configuration)."""
     cfg = tuning.resolve("rmsnorm", br=br)
     n, d = x.shape
     eff = {"br": min(cfg["br"], n)}
@@ -29,4 +30,9 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, br: Optional[int] = None):
     vmem = 2 * (eff["br"] * d * (2 * x.dtype.itemsize + 4)
                 + d * scale.dtype.itemsize)
     tuning.validate_blocks("rmsnorm", eff, dims={"br": n}, vmem_bytes=vmem)
-    return _rmsnorm(x, scale, eps, eff["br"])
+    return eff
+
+
+def rmsnorm(x, scale, *, eps: float = 1e-6, br: Optional[int] = None):
+    """Row-blocked RMSNorm; ``br`` defaults to the tuned block size."""
+    return _rmsnorm(x, scale, eps, blocks(x, scale, br=br)["br"])

@@ -4,7 +4,7 @@
 boundary (kwarg > env > tuned.json > builtin).
 """
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -45,14 +45,9 @@ def _ssd(x, dt, A, B, C, D, chunk: int, init_state=None):
     return y.astype(x.dtype), hfin
 
 
-def ssd(x, dt, A, B, C, D, *, chunk: Optional[int] = None,
-        init_state=None):
-    """Same contract as repro.models.layers.ssd_chunked (g=1 folded).
-
-    x [b,l,h,p]; dt [b,l,h]; A [h]; B,C [b,l,g,n]; D [h].
-    Returns (y [b,l,h,p], final_state [b,h,p,n]).  ``chunk`` defaults to
-    the tuned intra-chunk length.
-    """
+def blocks(x, B, *, chunk: Optional[int] = None) -> Dict[str, int]:
+    """The validated, shape-clamped chunk :func:`ssd` runs with
+    (kwarg > tuned configuration)."""
     cfg = tuning.resolve("ssd_scan", chunk=chunk)
     _, l, _, p = x.shape
     n = B.shape[-1]
@@ -64,4 +59,16 @@ def ssd(x, dt, A, B, C, D, *, chunk: Optional[int] = None,
     vmem = 2 * 4 * (2 * Q * p + 2 * Q * n + 2 * Q + p * n + 3 * Q * Q)
     tuning.validate_blocks("ssd_scan", eff, dims={"chunk": l},
                            vmem_bytes=vmem)
-    return _ssd(x, dt, A, B, C, D, eff["chunk"], init_state=init_state)
+    return eff
+
+
+def ssd(x, dt, A, B, C, D, *, chunk: Optional[int] = None,
+        init_state=None):
+    """Same contract as repro.models.layers.ssd_chunked (g=1 folded).
+
+    x [b,l,h,p]; dt [b,l,h]; A [h]; B,C [b,l,g,n]; D [h].
+    Returns (y [b,l,h,p], final_state [b,h,p,n]).  ``chunk`` defaults to
+    the tuned intra-chunk length.
+    """
+    return _ssd(x, dt, A, B, C, D, blocks(x, B, chunk=chunk)["chunk"],
+                init_state=init_state)

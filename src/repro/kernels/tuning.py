@@ -53,13 +53,13 @@ DISABLE_ENV = "REPRO_TUNED"
 #: installed package tree (tests, hermetic CI workspaces).
 DIR_ENV = "REPRO_TUNED_DIR"
 
-#: Conservative per-core VMEM budget for block validation.  The kernels
-#: are tiled for TPU v5e (~128 MiB VMEM/core, see repro.kernels); the
-#: estimate the wrappers pass in is the single-step working set, doubled
-#: for pipelining, so absurd blocks fail here with a readable error
-#: instead of deep inside Pallas lowering.
-VMEM_BUDGET_BYTES = 128 * 1024 * 1024
-VMEM_ENV = "REPRO_VMEM_BUDGET_BYTES"
+#: Scoped-VMEM limit every tunable kernel is compiled under: each
+#: ``pallas_call`` passes it as ``vmem_limit_bytes``, so it is the
+#: budget ``validate_blocks`` holds the wrappers' working-set estimates
+#: to.  32 MiB is a quarter of a v5e core's 128 MiB of VMEM and twice
+#: Mosaic's default scoped limit (16 MiB), which a block refused by the
+#: chip's compiler would otherwise hit inside lowering.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 _TUNED_CACHE: Dict[str, Optional[Dict[str, int]]] = {}
 _OVERRIDES: Dict[str, Dict[str, int]] = {}
@@ -189,17 +189,6 @@ def write_tuned(kernel: str, payload: Mapping[str, Any],
     return out
 
 
-def vmem_budget_bytes() -> int:
-    env = os.environ.get(VMEM_ENV)
-    if env:
-        try:
-            return int(env)
-        except ValueError:
-            log.warning("%s=%r is not an integer; using default", VMEM_ENV,
-                        env)
-    return VMEM_BUDGET_BYTES
-
-
 def validate_blocks(kernel: str, blocks: Mapping[str, int],
                     dims: Mapping[str, int],
                     vmem_bytes: Optional[float] = None) -> None:
@@ -208,7 +197,8 @@ def validate_blocks(kernel: str, blocks: Mapping[str, int],
     ``blocks`` are the effective (shape-clamped) knob values, ``dims``
     maps each knob to the array dimension it must divide, and
     ``vmem_bytes`` is the wrapper's estimate of the per-grid-step VMEM
-    working set (pipelining double-buffer included).  Raises a
+    working set (pipelining double-buffer included), held to
+    :data:`VMEM_LIMIT_BYTES`.  Raises a
     ``ValueError`` naming the offending knob(s) instead of letting the
     kernel die in lowering with a shape assert."""
     _check_kernel(kernel)
@@ -220,12 +210,12 @@ def validate_blocks(kernel: str, blocks: Mapping[str, int],
         elif dim % block:
             problems.append(f"{knob}={block} does not divide the "
                             f"dimension it tiles ({dim})")
-    budget = vmem_budget_bytes()
-    if vmem_bytes is not None and vmem_bytes > budget:
+    if vmem_bytes is not None and vmem_bytes > VMEM_LIMIT_BYTES:
         cfg = ", ".join(f"{k}={v}" for k, v in sorted(blocks.items()))
         problems.append(
             f"blocks ({cfg}) need ~{vmem_bytes / 2 ** 20:.0f} MiB of VMEM "
-            f"per grid step, over the {budget / 2 ** 20:.0f} MiB budget")
+            f"per grid step, over the {VMEM_LIMIT_BYTES / 2 ** 20:.0f} MiB "
+            f"scoped-VMEM limit the kernel is compiled under")
     if problems:
         raise ValueError(
             f"invalid block config for kernel {kernel!r}: "

@@ -34,7 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed import partition as part
 from repro.distributed.logical import default_rules, logical_rules
 from repro.launch import inputs as inp
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import build, get_config, list_archs
 from repro.models.config import ModelConfig
 from repro.roofline.analysis import analyze_compiled, model_flops
@@ -118,10 +118,9 @@ def lower_cell(arch: str, shape: str, *, multi_pod: bool = False,
     if overrides.get("dp_layout"):
         # §Perf re-mesh experiment: same 256/512 chips, logical axes
         # (data=256, model=1) — pure DP+ZeRO, no TP activation psums.
-        import jax as _jax
         mshape = (2, 256, 1) if multi_pod else (256, 1)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        mesh = _jax.make_mesh(mshape, axes)
+        mesh = make_mesh(mshape, axes)
         mesh_name = ("pod2x256x1" if multi_pod else "pod256x1")
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)

@@ -28,6 +28,7 @@ from repro.distributed.logical import default_rules, logical_rules
 from repro.distributed.straggler import StragglerWatchdog
 from repro.launch.mesh import make_host_mesh
 from repro.models import build, get_config
+from repro.models.config import ModelConfig
 from repro.train import AdamWConfig, make_train_step
 from repro.train.step import make_init_fn
 
@@ -38,6 +39,34 @@ def _sharding(mesh, spec_tree):
     return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), spec_tree,
         is_leaf=lambda x: isinstance(x, P))
+
+
+def sharded_train_fns(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh,
+                      microbatches: int = 1):
+    """The train state layout and the jitted programs ``train`` runs on
+    ``mesh``: parameters split by the logical rules, AdamW moments
+    ZeRO-sharded over ``data``, the state donated to each step.
+
+    Returns ``(state_structs, state_shardings, init, step)``; call
+    ``init(key)`` and ``step(state, batch)`` inside ``with mesh,
+    logical_rules(default_rules(cfg, mesh))``.
+    """
+    api = build(cfg)
+    init_fn = make_init_fn(api, opt_cfg)
+    state_structs = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    params = state_structs["params"]
+    zero = part.zero_shard_specs(cfg, params, mesh)
+    state_specs = {"params": part.param_specs(cfg, params, mesh),
+                   "opt": {"m": zero, "v": zero, "count": P()},
+                   "step": P()}
+    state_shardings = _sharding(mesh, state_specs)
+    init = jax.jit(init_fn, out_shardings=state_shardings)
+    step = jax.jit(
+        make_train_step(api, opt_cfg, num_microbatches=microbatches),
+        in_shardings=(state_shardings, None),
+        out_shardings=(state_shardings, None),
+        donate_argnums=(0,))
+    return state_structs, state_shardings, init, step
 
 
 def train(arch: str, steps: int = 100, global_batch: int = 8,
@@ -53,7 +82,6 @@ def train(arch: str, steps: int = 100, global_batch: int = 8,
     if reduced:
         cfg = cfg.reduced()
     cfg = cfg.override(**(overrides or {}))
-    api = build(cfg)
     mesh = make_host_mesh(model=model_parallel)
     rules = default_rules(cfg, mesh)
     opt_cfg = AdamWConfig(lr=lr, total_steps=steps,
@@ -62,16 +90,8 @@ def train(arch: str, steps: int = 100, global_batch: int = 8,
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=seq_len,
                           global_batch=global_batch, seed=seed)
 
-    init_fn = make_init_fn(api, opt_cfg)
-    state_structs = jax.eval_shape(init_fn, jax.random.PRNGKey(seed))
-    pspecs = part.param_specs(cfg, state_structs["params"], mesh)
-    opt_specs = {"m": part.zero_shard_specs(cfg, state_structs["params"],
-                                            mesh),
-                 "v": part.zero_shard_specs(cfg, state_structs["params"],
-                                            mesh),
-                 "count": P()}
-    state_specs = {"params": pspecs, "opt": opt_specs, "step": P()}
-    state_shardings = _sharding(mesh, state_specs)
+    state_structs, state_shardings, init, step_fn = sharded_train_fns(
+        cfg, opt_cfg, mesh, microbatches)
 
     ckpt = CheckpointManager(ckpt_dir, save_interval=ckpt_every) \
         if ckpt_dir else None
@@ -83,15 +103,8 @@ def train(arch: str, steps: int = 100, global_batch: int = 8,
             state = jax.device_put(host_state, state_shardings)
             log.info("resumed at step %d", start)
         else:
-            state = jax.jit(init_fn, out_shardings=state_shardings)(
-                jax.random.PRNGKey(seed))
+            state = init(jax.random.PRNGKey(seed))
             start = 0
-
-        step_fn = jax.jit(
-            make_train_step(api, opt_cfg, num_microbatches=microbatches),
-            in_shardings=(state_shardings, None),
-            out_shardings=(state_shardings, None),
-            donate_argnums=(0,))
 
         if ckpt:
             latest: Dict[str, Any] = {"step": start, "state": state}
@@ -128,7 +141,8 @@ def train(arch: str, steps: int = 100, global_batch: int = 8,
             pipe.close()
 
     total = time.perf_counter() - t_start
-    return {"first_loss": losses[0] if losses else None,
+    return {"losses": losses,
+            "first_loss": losses[0] if losses else None,
             "last_loss": losses[-1] if losses else None,
             "steps": len(losses), "seconds": total,
             "tokens_per_s": len(losses) * global_batch * seq_len / total}

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.compat import shard_map as shard_map_compat
 from repro.distributed.logical import constrain
 
 Params = Dict[str, Any]
@@ -757,11 +756,11 @@ def moe_shard_map(p: Params, x: jax.Array, cfg, rules
         y = lax.psum(y, ep_axis)            # combine across expert ranks
         return y.reshape(B, S, d), aux
 
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, w_specs),
         out_specs=(x_spec, P()),
-        check=False,
+        check_vma=False,
     )(x, weights)
     if cfg.moe_num_shared:
         y = y + mlp(p["shared"], x, cfg.act)

@@ -17,9 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ParamSpace, Scope, State, benchmark
-from repro.core.compat import shard_map
 from repro.core.registry import BenchmarkRegistry
 from repro.core.sysinfo import TPU_V5E
+from repro.launch.mesh import make_mesh
 
 NAME = "comm"
 
@@ -44,14 +44,14 @@ def _register(registry: BenchmarkRegistry) -> None:
     def psum_setup(params):
         n = jax.device_count()
         elems = params.bytes // 4
-        mesh = jax.make_mesh((n,), ("x",))
+        mesh = make_mesh((n,), ("x",))
         x = jnp.ones((n, elems), jnp.float32)
 
         @jax.jit
         def f(x):
-            return shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
-                             in_specs=jax.sharding.PartitionSpec("x"),
-                             out_specs=jax.sharding.PartitionSpec())(x)
+            return jax.shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
+                                 in_specs=jax.sharding.PartitionSpec("x"),
+                                 out_specs=jax.sharding.PartitionSpec())(x)
         return f, x
 
     @benchmark(scope=NAME, registry=registry)

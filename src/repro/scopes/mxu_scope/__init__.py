@@ -9,15 +9,16 @@ of the three hand-copied per-variant families this scope used to carry.
 The fixture allocates operands and builds the jitted callable untimed;
 the runner's warm phase measures the first call (trace + XLA compile)
 as ``compile_time_s``, so the steady-state numbers never include
-compilation.  Reports achieved FLOP/s plus (for the TPU target) the
-modeled roofline fraction at v5e peak.
+compilation.  Reports achieved FLOP/s plus, on a chip with published
+peaks (repro.core.sysinfo.DEVICE_PEAKS), the least time the chip's bf16
+peak allows; the CPU gets no such counter.
 """
 import jax
 import jax.numpy as jnp
 
 from repro.core import ParamSpace, Scope, State, benchmark
 from repro.core.registry import BenchmarkRegistry
-from repro.core.sysinfo import TPU_V5E
+from repro.core.sysinfo import device_peaks
 
 NAME = "mxu"
 
@@ -53,7 +54,10 @@ def _register(registry: BenchmarkRegistry) -> None:
         n = state.params.n
         flops = 2.0 * n * n * n
         state.counters["flops_per_call"] = flops
-        state.counters["model_roofline_s"] = flops / TPU_V5E["peak_bf16_flops"]
+        peaks = device_peaks(next(iter(x.devices())))
+        if peaks is not None:
+            state.counters["model_roofline_s"] = \
+                flops / peaks["peak_bf16_flops"]
         state.set_items_processed(int(flops))
 
     # pallas stays a single f32/256 point (interpret mode is slow on CPU);

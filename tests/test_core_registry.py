@@ -57,7 +57,8 @@ def test_remove_scope():
     assert len(reg) == 0
 
 
-@given(st.lists(st.lists(st.integers(1, 8), min_size=1, max_size=3),
+@given(st.lists(st.lists(st.integers(1, 8), min_size=1, max_size=3,
+                         unique=True),     # duplicate arg-sets are refused
                 min_size=1, max_size=3))
 @settings(max_examples=25, deadline=None)
 def test_args_product_cardinality(lists):

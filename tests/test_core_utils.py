@@ -55,3 +55,25 @@ def test_timer_and_logger():
         sum(range(1000))
     assert t.elapsed >= 0
     log.info("ok")                        # no crash, handler configured
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_directory(env_dir, tmp_path):
+    """Without JAX_COMPILATION_CACHE_DIR the persistent compile cache is
+    the fixed ``.jax_cache`` at the checkout root; with it, JAX's own
+    reading of the variable is left alone."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import repro, jax; print(jax.config.jax_compilation_cache_dir)"],
+        capture_output=True, text=True, timeout=120, env=env, check=True)
+    want = str(tmp_path / env_dir) if env_dir \
+        else os.path.join(root, ".jax_cache")
+    assert r.stdout.strip() == want

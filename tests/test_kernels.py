@@ -91,9 +91,9 @@ def test_ssd_kernel(l, h, chunk):
 
 def test_tilings_are_tpu_aligned():
     """Structural check: default blocks are MXU-aligned multiples of 128
-    and fit comfortably in v5e VMEM."""
-    from repro.core.sysinfo import TPU_V5E
-    vmem = TPU_V5E["vmem_bytes"]
+    and fit comfortably in the scoped VMEM the kernels compile under."""
+    from repro.kernels.tuning import VMEM_LIMIT_BYTES
+    vmem = VMEM_LIMIT_BYTES
     bm = bn = bk = 512
     assert bm % 128 == 0 and bn % 128 == 0 and bk % 128 == 0
     working = (bm * bk + bk * bn) * 2 + bm * bn * 4

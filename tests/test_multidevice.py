@@ -34,6 +34,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.train.step import make_init_fn
         from repro.distributed import partition as part
         from repro.distributed.logical import default_rules, logical_rules
+        from repro.launch.mesh import make_mesh
 
         cfg = get_config("llama3.2-1b").reduced().override(num_layers=2)
         api = build(cfg)
@@ -46,8 +47,8 @@ def test_sharded_train_step_matches_single_device():
         # single-device result
         state = init_fn(key)
         s1, m1 = jax.jit(step_fn)(state, batch)
-        # sharded result
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        # sharded result, on the mesh the launcher builds
+        mesh = make_mesh((2, 4), ("data", "model"))
         pspecs = part.param_specs(cfg, jax.eval_shape(init_fn, key)["params"],
                                   mesh)
         shard = lambda t: jax.tree_util.tree_map(

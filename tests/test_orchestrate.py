@@ -56,6 +56,28 @@ def test_inline_merged_matches_sequential_runner():
         [frozenset(r) for r in seq["benchmarks"]]
 
 
+def test_worker_processes_refuse_to_share_a_chip(monkeypatch):
+    """Worker modes start processes that each initialize JAX: without
+    JAX_PLATFORMS=cpu they could reach a chip, so run/ci/execute refuse
+    at once, naming the reason, and touch no device to decide."""
+    from repro.core.ci import ci_main
+    from repro.core.main import run_main
+    from repro.core.orchestrate import chip_sharing_error
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    msg = chip_sharing_error(OrchestratorOptions(jobs=2))
+    assert "one process at a time" in msg and "JAX_PLATFORMS=cpu" in msg
+    assert chip_sharing_error(OrchestratorOptions(jobs=1)) is None
+    assert chip_sharing_error(
+        OrchestratorOptions(jobs=1, isolate="subprocess")) is not None
+    mgr = make_mgr(["repro.scopes.example_scope"])
+    with pytest.raises(ValueError, match="--jobs 2"):
+        execute(mgr, mgr.registry, OrchestratorOptions(jobs=2, run=FAST))
+    assert run_main(["--jobs", "2"]) == 2
+    assert ci_main(["--jobs", "2", "--results-dir", "unused"]) == 2
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert chip_sharing_error(OrchestratorOptions(jobs=2)) is None
+
+
 @pytest.mark.slow
 def test_parallel_subprocess_matches_inline(monkeypatch, tmp_path):
     """--jobs 2 scope-grained subprocess run: same names/schema as

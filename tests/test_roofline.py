@@ -60,3 +60,21 @@ ENTRY %main (p: f32[4]) -> f32[4] {
 }
 """
     assert cpu_widening_artifact_bytes(text) == 8 * 64 * 4
+
+
+def test_device_peaks_are_looked_up_by_device_kind():
+    """A measured time is divided only by the peaks of the device it ran
+    on: the CPU has none, and an unknown chip is an error, not a v5e."""
+    from types import SimpleNamespace
+
+    import pytest
+
+    from repro.core.sysinfo import DEVICE_PEAKS, device_peaks
+    v5e = device_peaks(SimpleNamespace(platform="tpu",
+                                       device_kind="TPU v5 lite"))
+    assert v5e["peak_bf16_flops"] == 197e12
+    assert v5e["hbm_bandwidth"] == 819e9
+    assert device_peaks(jax.devices()[0]) is None         # the CPU
+    with pytest.raises(ValueError, match="TPU v9"):
+        device_peaks(SimpleNamespace(platform="tpu", device_kind="TPU v9"))
+    assert set(DEVICE_PEAKS) == {"TPU v5 lite"}

@@ -324,13 +324,13 @@ def test_validate_blocks_reports_every_problem():
     assert "repro tune" in msg            # remediation, not a stack trace
 
 
-def test_validate_blocks_enforces_the_vmem_budget(monkeypatch):
-    monkeypatch.setenv(tuning.VMEM_ENV, str(1024))
+def test_validate_blocks_enforces_the_vmem_budget():
+    limit = tuning.VMEM_LIMIT_BYTES
     with pytest.raises(ValueError, match="VMEM"):
         tuning.validate_blocks("matmul", {"bm": 128}, dims={"bm": 128},
-                               vmem_bytes=2048.0)
+                               vmem_bytes=limit + 1.0)
     tuning.validate_blocks("matmul", {"bm": 128}, dims={"bm": 128},
-                           vmem_bytes=512.0)
+                           vmem_bytes=float(limit))
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,153 @@
+"""The Pallas kernels compile for a TPU v5e at main-path widths.
+
+Each test compiles one kernel, at the blocks its public wrapper resolves
+(tuned artifact, shape clamp, VMEM validation), for one chip of a
+described ``v5e:2x2`` topology: the TPU compiler is installed even where
+no chip is attached.  A kernel that only interpret mode accepts (a block
+not aligned to the TPU tiling, more scoped VMEM than the limit) fails
+here, with no chip time spent.  Nothing runs; the compiled program must
+contain the Mosaic kernel (``tpu_custom_call``).
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import ops as flash_ops
+from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.histogram.kernel import histogram_pallas
+from repro.kernels.matmul import ops as matmul_ops
+from repro.kernels.matmul.kernel import matmul_pallas
+from repro.kernels.rmsnorm import ops as rmsnorm_ops
+from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
+from repro.kernels.ssd_scan import ops as ssd_ops
+from repro.kernels.ssd_scan.kernel import ssd_chunk_pallas
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    # the TPU library logs under /tmp unless told otherwise
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "can't here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile written to the persistent cache cannot be read back
+    # without a chip; keep these compiles out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_matmul_compiles(one_chip):
+    x = jax.ShapeDtypeStruct((4096, 4096), jnp.bfloat16, sharding=one_chip)
+    eff = matmul_ops.blocks(x, x)
+    text = _compile_text(functools.partial(matmul_pallas, **eff), x, x)
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_compiles(one_chip):
+    q = jax.ShapeDtypeStruct((1, 2048, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    eff = flash_ops.blocks(q, q)
+    text = _compile_text(functools.partial(flash_attention_pallas, **eff),
+                         q, q, q)
+    assert "tpu_custom_call" in text
+
+
+def test_rmsnorm_compiles(one_chip):
+    x = jax.ShapeDtypeStruct((8192, 2048), jnp.bfloat16, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((2048,), jnp.float32, sharding=one_chip)
+    eff = rmsnorm_ops.blocks(x, s)
+    text = _compile_text(functools.partial(rmsnorm_pallas, **eff), x, s)
+    assert "tpu_custom_call" in text
+
+
+def test_rmsnorm_block_over_the_vmem_limit_fails_validation(one_chip):
+    """br=2048 at d=2048 bf16 needs more scoped VMEM than the kernels are
+    compiled under: the chip's compiler refuses it, and the wrapper
+    refuses it first, with the readable message."""
+    x = jax.ShapeDtypeStruct((8192, 2048), jnp.bfloat16, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((2048,), jnp.float32, sharding=one_chip)
+    with pytest.raises(ValueError, match="scoped-VMEM limit"):
+        rmsnorm_ops.blocks(x, s, br=2048)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="vmem"):
+        _compile_text(functools.partial(rmsnorm_pallas, br=2048), x, s)
+
+
+def test_ssd_scan_compiles_at_mamba2_780m_widths(one_chip):
+    # mamba2-780m: 48 heads of 64, state 128, one 512-token sequence
+    b, l, h, p, n = 1, 512, 48, 64, 128
+    x = jax.ShapeDtypeStruct((b, l, h, p), jnp.bfloat16, sharding=one_chip)
+    dt = jax.ShapeDtypeStruct((b, l, h), jnp.float32, sharding=one_chip)
+    A = jax.ShapeDtypeStruct((h,), jnp.float32, sharding=one_chip)
+    B = jax.ShapeDtypeStruct((b, l, 1, n), jnp.bfloat16, sharding=one_chip)
+    Bf = jax.ShapeDtypeStruct((b, l, n), jnp.bfloat16, sharding=one_chip)
+    eff = ssd_ops.blocks(x, B)
+    text = _compile_text(functools.partial(ssd_chunk_pallas, **eff),
+                         x, dt, A, Bf, Bf)
+    assert "tpu_custom_call" in text
+
+
+def test_histogram_compiles(one_chip):
+    x = jax.ShapeDtypeStruct((1 << 20,), jnp.int32, sharding=one_chip)
+    text = _compile_text(functools.partial(histogram_pallas, nbins=256), x)
+    assert "tpu_custom_call" in text
+
+
+def _flash_gqa(f32):
+    q, kv = f32((2, 128, 2, 32)), f32((2, 128, 1, 32))
+    return (functools.partial(flash_attention_pallas, causal=True,
+                              **flash_ops.blocks(q, kv)), q, kv, kv)
+
+
+def _ssd_small(f32):
+    x, B = f32((2, 512, 4, 64)), f32((2, 512, 1, 64))
+    return (functools.partial(ssd_chunk_pallas, **ssd_ops.blocks(x, B)),
+            x, f32((2, 512, 4)), f32((4,)), f32((2, 512, 64)),
+            f32((2, 512, 64)))
+
+
+# The shapes the builtin scopes run the kernels at (`repro run` on a
+# chip), with the blocks the wrappers resolve for them.
+RUN_STAGE_CASES = {
+    "mxu/matmul": lambda f32: (
+        functools.partial(matmul_pallas, **matmul_ops.blocks(
+            f32((256, 256)), f32((256, 256)))),
+        f32((256, 256)), f32((256, 256))),
+    "linalg/matmul_rect": lambda f32: (
+        functools.partial(matmul_pallas, **matmul_ops.blocks(
+            f32((512, 256)), f32((256, 256)))),
+        f32((512, 256)), f32((256, 256))),
+    "nn/rmsnorm": lambda f32: (
+        functools.partial(rmsnorm_pallas, **rmsnorm_ops.blocks(
+            f32((1024, 1024)), f32((1024,)))),
+        f32((1024, 1024)), f32((1024,))),
+    "nn/flash_attention_pallas": _flash_gqa,
+    "nn/ssd_scan_pallas": _ssd_small,
+}
+
+
+@pytest.mark.parametrize("family", sorted(RUN_STAGE_CASES))
+def test_run_stage_kernel_shapes_compile(one_chip, family):
+    def f32(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    fn, *args = RUN_STAGE_CASES[family](f32)
+    assert "tpu_custom_call" in _compile_text(fn, *args)
