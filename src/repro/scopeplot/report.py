@@ -50,7 +50,7 @@ log = get_logger("report")
 _SYSINFO_KEYS = (
     "date", "host_name", "machine", "model_name", "num_cpus",
     "jax_version", "backend", "device_count", "device_kind",
-    "target_hardware", "xla_flags", "scope_version",
+    "xla_flags", "scope_version",
 )
 
 

@@ -9,8 +9,7 @@ from repro.core.sysinfo import context_digest
 CTX = {"run_id": "r?", "date": "2026-07-31T00:00:00",
        "host_name": "fixturehost", "machine": "x86_64", "num_cpus": 8,
        "jax_version": "0.0-test", "backend": "cpu", "device_count": 1,
-       "device_kind": "cpu", "target_hardware": "tpu_v5e",
-       "scope_version": "1.0.0-jax"}
+       "device_kind": "cpu", "scope_version": "1.0.0-jax"}
 
 
 def make_doc(run_id, means, date="2026-07-31T00:00:00", errors=()):

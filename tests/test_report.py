@@ -25,7 +25,7 @@ GOLDEN = os.path.join(os.path.dirname(__file__), "data",
 CTX = {"date": "2026-07-31T00:00:00", "host_name": "fixturehost",
        "machine": "x86_64", "num_cpus": 8, "jax_version": "0.0-test",
        "backend": "cpu", "device_count": 1, "device_kind": "cpu",
-       "target_hardware": "tpu_v5e", "scope_version": "1.0.0-jax"}
+       "scope_version": "1.0.0-jax"}
 
 
 def gb_doc(run_id, means_us, date="2026-07-31T00:00:00"):
