@@ -1,0 +1,13 @@
+"""Device time of a train step in the chunked loss (ms a step): the
+vocabulary-wide output head and its cross-entropy, forward and backward.
+The leaf operations of ``jit_train_step`` whose HLO ``op_name`` carries
+the program's ``loss`` scope, over the traced steps (bench/scopes.py)."""
+from __future__ import annotations
+
+from typing import Dict, Optional
+
+from bench.scopes import train_scope_ms
+
+
+def read(ctx: Dict) -> Optional[float]:
+    return train_scope_ms(ctx, "loss")

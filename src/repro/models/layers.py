@@ -422,6 +422,7 @@ def _flash_vjp_bwd(causal, cq, ck, causal_skip, res, dout):
 _flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@jax.named_scope("attention")
 def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True,
                         chunk_q: int = 512, chunk_k: int = 512,
@@ -446,6 +447,7 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array,
     return _flash_attention(q, k, v, causal, cq, ck, causal_skip)
 
 
+@jax.named_scope("attention")
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      cache_len: jax.Array) -> jax.Array:
     """Single-token attention against a (padded) KV cache.
@@ -496,6 +498,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return o.astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def attention_block(p: Params, x: jax.Array, positions: jax.Array, *,
                     cfg, causal: bool = True) -> jax.Array:
     """Full self-attention sublayer (projections + rope + attention)."""
@@ -529,6 +532,7 @@ def init_mlp(key, d: int, ff: int, act: str = "silu") -> Params:
     return p
 
 
+@jax.named_scope("mlp")
 def mlp(p: Params, x: jax.Array, act: str = "silu") -> jax.Array:
     up = constrain(x @ p["w_up"].astype(x.dtype), "batch", None, "ff")
     if act == "silu":
@@ -776,6 +780,7 @@ def _dpsize(mesh, batch_axes_) -> int:
     return n
 
 
+@jax.named_scope("mlp")
 def moe_layer(p: Params, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
     from repro.distributed.logical import active_rules
     rules = active_rules()
@@ -1024,10 +1029,12 @@ def init_embed(key, V: int, d: int) -> Params:
     return {"table": embed_init(key, (V, d))}
 
 
+@jax.named_scope("embed")
 def embed(p: Params, tokens: jax.Array, dtype) -> jax.Array:
     return jnp.take(p["table"], tokens, axis=0).astype(dtype)
 
 
+@jax.named_scope("unembed")
 def unembed(table: jax.Array, x: jax.Array, dtype) -> jax.Array:
     return (x @ table.T.astype(x.dtype)).astype(dtype)
 
@@ -1044,6 +1051,7 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
     return jnp.mean(nll)
 
 
+@jax.named_scope("loss")
 def chunked_loss(table: jax.Array, x: jax.Array, labels: jax.Array,
                  chunk: int, logits_dtype) -> jax.Array:
     """Cross-entropy without materializing [B,S,V]: scan over S chunks.

@@ -72,22 +72,24 @@ def _block_apply(cfg: ModelConfig, p: Params, x: jax.Array,
                  positions: jax.Array, collect_kv: bool):
     """One transformer block.  Returns (x, aux, (k, v) | None)."""
     h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
-    q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads, cfg.hd,
-                     cfg.qk_norm, cfg.norm_eps)
-    q = L.apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections,
-                     cfg.use_rope)
-    k = L.apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections,
-                     cfg.use_rope)
-    if cfg.attn_impl == "naive":
-        o = L.naive_attention(q, k, v, causal=True)
-    else:
-        o = L.flash_attention_xla(q, k, v, causal=True,
-                                  chunk_q=cfg.attn_chunk_q,
-                                  chunk_k=cfg.attn_chunk_k,
-                                  causal_skip=cfg.causal_skip)
-    B, S = x.shape[:2]
-    x = x + o.reshape(B, S, cfg.num_heads * cfg.hd) @ \
-        p["attn"]["wo"].astype(x.dtype)
+    with jax.named_scope("attention"):
+        q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
+                         cfg.hd, cfg.qk_norm, cfg.norm_eps)
+        q = L.apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections,
+                         cfg.use_rope)
+        k = L.apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections,
+                         cfg.use_rope)
+        if cfg.attn_impl == "naive":
+            o = L.naive_attention(q, k, v, causal=True)
+        else:
+            o = L.flash_attention_xla(q, k, v, causal=True,
+                                      chunk_q=cfg.attn_chunk_q,
+                                      chunk_k=cfg.attn_chunk_k,
+                                      causal_skip=cfg.causal_skip)
+        B, S = x.shape[:2]
+        o = o.reshape(B, S, cfg.num_heads * cfg.hd) @ \
+            p["attn"]["wo"].astype(x.dtype)
+    x = x + o
 
     h = L.rms_norm(p["ln2"], x, cfg.norm_eps)
     if "moe" in p:
@@ -226,19 +228,21 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
     def block(x, inp):
         p, k_c, v_c = inp
         h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
-        q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
-                         cfg.hd, cfg.qk_norm, cfg.norm_eps)
-        q = L.apply_rope(q, positions, cfg.rope_theta,
-                         cfg.mrope_sections, cfg.use_rope)
-        k = L.apply_rope(k, positions, cfg.rope_theta,
-                         cfg.mrope_sections, cfg.use_rope)
-        k_c = lax.dynamic_update_slice_in_dim(
-            k_c, k.astype(k_c.dtype), pos, axis=1)
-        v_c = lax.dynamic_update_slice_in_dim(
-            v_c, v.astype(v_c.dtype), pos, axis=1)
-        o = L.decode_attention(q, k_c, v_c, pos + 1)
-        x = x + o.reshape(B, 1, cfg.num_heads * cfg.hd) @ \
-            p["attn"]["wo"].astype(x.dtype)
+        with jax.named_scope("attention"):
+            q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
+                             cfg.hd, cfg.qk_norm, cfg.norm_eps)
+            q = L.apply_rope(q, positions, cfg.rope_theta,
+                             cfg.mrope_sections, cfg.use_rope)
+            k = L.apply_rope(k, positions, cfg.rope_theta,
+                             cfg.mrope_sections, cfg.use_rope)
+            k_c = lax.dynamic_update_slice_in_dim(
+                k_c, k.astype(k_c.dtype), pos, axis=1)
+            v_c = lax.dynamic_update_slice_in_dim(
+                v_c, v.astype(v_c.dtype), pos, axis=1)
+            o = L.decode_attention(q, k_c, v_c, pos + 1)
+            o = o.reshape(B, 1, cfg.num_heads * cfg.hd) @ \
+                p["attn"]["wo"].astype(x.dtype)
+        x = x + o
         h = L.rms_norm(p["ln2"], x, cfg.norm_eps)
         if "moe" in p:
             m, _ = L.moe_layer(p["moe"], h, cfg)
@@ -275,17 +279,19 @@ def decode_step_ragged(cfg: ModelConfig, params: Params, tokens: jax.Array,
     def block(x, inp):
         p, k_c, v_c = inp
         h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
-        q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
-                         cfg.hd, cfg.qk_norm, cfg.norm_eps)
-        q = L.apply_rope(q, positions, cfg.rope_theta,
-                         cfg.mrope_sections, cfg.use_rope)
-        k = L.apply_rope(k, positions, cfg.rope_theta,
-                         cfg.mrope_sections, cfg.use_rope)
-        k_c = k_c.at[bidx, pos].set(k[:, 0].astype(k_c.dtype))
-        v_c = v_c.at[bidx, pos].set(v[:, 0].astype(v_c.dtype))
-        o = L.decode_attention(q, k_c, v_c, pos + 1)
-        x = x + o.reshape(B, 1, cfg.num_heads * cfg.hd) @ \
-            p["attn"]["wo"].astype(x.dtype)
+        with jax.named_scope("attention"):
+            q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
+                             cfg.hd, cfg.qk_norm, cfg.norm_eps)
+            q = L.apply_rope(q, positions, cfg.rope_theta,
+                             cfg.mrope_sections, cfg.use_rope)
+            k = L.apply_rope(k, positions, cfg.rope_theta,
+                             cfg.mrope_sections, cfg.use_rope)
+            k_c = k_c.at[bidx, pos].set(k[:, 0].astype(k_c.dtype))
+            v_c = v_c.at[bidx, pos].set(v[:, 0].astype(v_c.dtype))
+            o = L.decode_attention(q, k_c, v_c, pos + 1)
+            o = o.reshape(B, 1, cfg.num_heads * cfg.hd) @ \
+                p["attn"]["wo"].astype(x.dtype)
+        x = x + o
         h = L.rms_norm(p["ln2"], x, cfg.norm_eps)
         if "moe" in p:
             m, _ = L.moe_layer(p["moe"], h, cfg)

@@ -22,16 +22,21 @@ from __future__ import annotations
 import collections
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, Iterator, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.logging import get_logger
+from repro.core.spans import span
 from repro.models.api import ModelApi
 
 log = get_logger("serve")
+
+#: Step records an engine keeps: the newest this many ``step()`` calls
+#: (about 100 minutes of 90 ms steps).
+MAX_STEP_RECORDS = 65_536
 
 
 @dataclass
@@ -43,6 +48,7 @@ class Request:
     # filled by the engine:
     output: List[int] = field(default_factory=list)
     submitted_at: float = 0.0
+    admitted_at: Optional[float] = None    # left the queue for a slot
     first_token_at: Optional[float] = None
     done_at: Optional[float] = None
     prompt_len: int = 0
@@ -67,6 +73,45 @@ class ServeConfig:
     fence_timestamps: bool = True
 
 
+@dataclass(slots=True)
+class StepRecord:
+    """What one ``ServeEngine.step()`` did, on the host's clock.
+
+    ``spans`` holds the host seconds of each ``engine.*`` span the step
+    opened (``engine.admit`` summed over its admissions); ``end`` is
+    taken after the step's tokens reached the host."""
+    start: float
+    end: float = 0.0
+    admitted: List[int] = field(default_factory=list)     # uids
+    prompt_tokens: int = 0          # the admitted prompts' real tokens
+    padded_tokens: int = 0          # ... padded to their buckets
+    live: int = 0                   # slots the step decoded
+    queue_depth: int = 0            # queued + in flight after admission
+    spans: Dict[str, float] = field(default_factory=dict)
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
+class _QueueDepths:
+    """Read-only view of the step records' queue depths, oldest first."""
+
+    def __init__(self, records: Deque[StepRecord]):
+        self._records = records
+
+    def __len__(self) -> int:
+        return len(self._records)
+
+    def __iter__(self) -> Iterator[int]:
+        return (r.queue_depth for r in self._records)
+
+    def __getitem__(self, i: Union[int, slice]):
+        if isinstance(i, slice):
+            return list(self)[i]
+        return self._records[i].queue_depth
+
+
 class ServeEngine:
     """Single-host engine driving a ModelApi; the multi-pod serve path
     reuses the same step functions under pjit (launch/serve.py)."""
@@ -88,16 +133,25 @@ class ServeEngine:
         self.cache = api.init_cache(cfg.max_batch, cfg.max_len,
                                     cfg.cache_dtype)
         self.cache["pos"] = jnp.zeros((cfg.max_batch,), jnp.int32)
-        self._decode = jax.jit(
-            lambda p, t, c: transformer.decode_step_ragged(api.cfg, p, t, c))
+
+        def decode_step(p, t, c):
+            return transformer.decode_step_ragged(api.cfg, p, t, c)
+        # a named function: the trace calls the program jit_decode_step
+        self._decode = jax.jit(decode_step)
         self._prefill_cache = {}
         # host-side per-slot position clocks (prefix + decoded tokens):
         # max_len exhaustion is a host decision, it must not force the
         # device cache
         self._slot_pos = [0] * cfg.max_batch
-        #: queued + in-flight request count sampled once per step() —
-        #: the queue-depth series latency meters average
-        self.queue_depth_log: List[int] = []
+        #: one record per step(), the newest MAX_STEP_RECORDS
+        self.step_records: Deque[StepRecord] = collections.deque(
+            maxlen=MAX_STEP_RECORDS)
+
+    @property
+    def queue_depth_log(self) -> _QueueDepths:
+        """Queued + in-flight request count sampled once per step() —
+        the queue-depth series latency meters average."""
+        return _QueueDepths(self.step_records)
 
     # -- public API -------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_tokens: int = 32,
@@ -132,13 +186,16 @@ class ServeEngine:
         """One engine step: admit from the queue, decode every live slot
         one token.  Returns the requests that finished this step (empty
         when the pool is idle).  ``run`` is a loop over this; open-loop
-        drivers interleave it with scheduled ``submit`` calls."""
+        drivers interleave it with scheduled ``submit`` calls.  Each
+        call appends a :class:`StepRecord` to ``step_records``."""
+        rec = StepRecord(start=time.perf_counter())
+        self.step_records.append(rec)
         self._admit()
-        depth = len(self.queue) + sum(1 for s in self.slots if s is not None)
-        self.queue_depth_log.append(depth)
-        if not any(s is not None for s in self.slots):
-            return []
-        return self._decode_step()
+        rec.live = sum(1 for s in self.slots if s is not None)
+        rec.queue_depth = len(self.queue) + rec.live
+        done = self._decode_step() if rec.live else []
+        rec.end = time.perf_counter()
+        return done
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
         """Drive until queue and slots drain.  Returns finished requests."""
@@ -159,52 +216,75 @@ class ServeEngine:
             f"(buckets: {self.cfg.prompt_buckets})")
 
     def _admit(self) -> None:
+        rec = self.step_records[-1]
         for i in range(self.cfg.max_batch):
             if self.slots[i] is not None or not self.queue:
                 continue
             req = self.queue.popleft()
-            self._prefill_into_slot(i, req)
+            req.admitted_at = time.perf_counter()
+            bucket = self._bucket(len(req.prompt))
+            with span("engine.admit", rec.spans, uid=req.uid, bucket=bucket,
+                      tokens=req.prompt_len):
+                self._prefill_into_slot(i, req, bucket)
+            rec.admitted.append(req.uid)
+            rec.prompt_tokens += req.prompt_len
+            rec.padded_tokens += bucket
             self.slots[i] = req
 
-    def _prefill_into_slot(self, slot: int, req: Request) -> None:
+    def _prefill_into_slot(self, slot: int, req: Request, bucket: int
+                           ) -> None:
         """Per-slot prefill: bucket-padded single-row prefill, then splice
         the row's cache into the pool cache at ``slot``."""
-        bucket = self._bucket(len(req.prompt))
-        toks = np.zeros((1, bucket), np.int32)
-        n = min(len(req.prompt), bucket)
-        toks[0, :n] = req.prompt[:n]
-        if bucket not in self._prefill_cache:
-            def one_row_prefill(params, tokens, n):
-                cache = self.api.init_cache(1, self.cfg.max_len,
-                                            self.cfg.cache_dtype)
-                return self.api.prefill(params, {"tokens": tokens}, cache,
-                                        logit_pos=n - 1)
-            self._prefill_cache[bucket] = jax.jit(one_row_prefill)
-        logits_row, row_cache = self._prefill_cache[bucket](
-            self.params, toks, n)
-        # right-padded prompt: this slot's clock is n, so padded keys
-        # beyond position n are masked by the per-slot prefix length
-        row_cache = dict(row_cache, pos=jnp.asarray([n], jnp.int32))
-        if self.cfg.fence_timestamps:
-            jax.block_until_ready(logits_row)
-        # fenced: the token is on the host — TTFT measures delivery;
-        # unfenced: the dispatch just returned — TTFT measures enqueue
-        req.first_token_at = time.perf_counter()
-        tok = int(jnp.argmax(logits_row[0, -1]))
+        spans = self.step_records[-1].spans
+        with span("engine.prefill", spans):
+            toks = np.zeros((1, bucket), np.int32)
+            n = min(len(req.prompt), bucket)
+            toks[0, :n] = req.prompt[:n]
+            if bucket not in self._prefill_cache:
+                def one_row_prefill(params, tokens, n):
+                    cache = self.api.init_cache(1, self.cfg.max_len,
+                                                self.cfg.cache_dtype)
+                    return self.api.prefill(params, {"tokens": tokens},
+                                            cache, logit_pos=n - 1)
+                self._prefill_cache[bucket] = jax.jit(one_row_prefill)
+            logits_row, row_cache = self._prefill_cache[bucket](
+                self.params, toks, n)
+            # right-padded prompt: this slot's clock is n, so padded keys
+            # beyond position n are masked by the per-slot prefix length
+            row_cache = dict(row_cache, pos=jnp.asarray([n], jnp.int32))
+            if self.cfg.fence_timestamps:
+                jax.block_until_ready(logits_row)
+            # fenced: the token is on the host — TTFT measures delivery;
+            # unfenced: the dispatch just returned — TTFT measures enqueue
+            req.first_token_at = time.perf_counter()
+        with span("engine.first_token", spans):
+            tok = int(jnp.argmax(logits_row[0, -1]))
         req.output.append(tok)
-        self.cache = _splice_row(self.cache, row_cache, slot)
+        with span("engine.splice", spans):
+            self.cache = _splice_row(self.cache, row_cache, slot)
         self._slot_pos[slot] = n
         self._pending_tok = getattr(self, "_pending_tok",
                                     np.zeros(self.cfg.max_batch, np.int32))
         self._pending_tok[slot] = tok
 
     def _decode_step(self) -> List[Request]:
-        toks = jnp.asarray(self._pending_tok)[:, None]
-        logits, self.cache = self._decode(self.params, toks, self.cache)
-        if self.cfg.fence_timestamps:
-            jax.block_until_ready(logits)
-        stamp = time.perf_counter()
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+        spans = self.step_records[-1].spans
+        with span("engine.decode", spans):
+            with span("engine.upload", spans):
+                toks = jnp.asarray(self._pending_tok)[:, None]
+            with span("engine.decode_wait", spans):
+                logits, self.cache = self._decode(self.params, toks,
+                                                  self.cache)
+                if self.cfg.fence_timestamps:
+                    jax.block_until_ready(logits)
+            stamp = time.perf_counter()
+            with span("engine.sample", spans):
+                nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+            with span("engine.retire", spans):
+                return self._retire(nxt, stamp)
+
+    def _retire(self, nxt: np.ndarray, stamp: float) -> List[Request]:
+        """Hand each live slot its token; free the slots that finished."""
         done: List[Request] = []
         for i, req in enumerate(self.slots):
             if req is None:
@@ -238,6 +318,8 @@ class ServeEngine:
             return {}
         ttft = [r.first_token_at - r.submitted_at for r in reqs
                 if r.first_token_at is not None]
+        wait = [r.admitted_at - r.submitted_at for r in reqs
+                if r.admitted_at is not None]
         lat = [r.done_at - r.submitted_at for r in reqs
                if r.done_at is not None]
         toks = sum(len(r.output) for r in reqs)
@@ -245,6 +327,7 @@ class ServeEngine:
         span = (max(finished) - min(r.submitted_at for r in reqs)
                 if finished else 0.0)
         return {"requests": len(reqs), "tokens": toks,
+                "queue_wait_mean_s": float(np.mean(wait)) if wait else 0.0,
                 "ttft_mean_s": float(np.mean(ttft)) if ttft else 0.0,
                 "latency_mean_s": float(np.mean(lat)) if lat else 0.0,
                 "throughput_tok_s": toks / span if span > 0 else 0.0}

@@ -39,6 +39,7 @@ def warmup_cosine(cfg: AdamWConfig) -> Callable[[jax.Array], jax.Array]:
     return schedule
 
 
+@jax.named_scope("optimizer")
 def clip_by_global_norm(grads, max_norm: float):
     leaves = jax.tree_util.tree_leaves(grads)
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
@@ -55,6 +56,7 @@ def adamw_init(params) -> Dict[str, Any]:
             "count": jnp.zeros((), jnp.int32)}
 
 
+@jax.named_scope("optimizer")
 def adamw_update(cfg: AdamWConfig, grads, opt_state, params,
                  schedule: Optional[Callable] = None):
     """One AdamW step.  Returns (new_params, new_opt_state, lr)."""
