@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.distributed.logical import constrain
+from repro.distributed.logical import active_rules, constrain
 
 Params = Dict[str, Any]
 
@@ -170,7 +170,10 @@ def repeat_kv(k: jax.Array, H: int) -> jax.Array:
 
     The Megatron treatment when TP > kv_heads: kv projections are
     replicated and each device takes the repeats its q-heads need — keeps
-    every attention einsum sharded cleanly on one head dim.
+    every attention einsum sharded cleanly on one head dim.  The flash
+    (train, prefill) paths always repeat; ``decode_attention`` repeats only
+    when the bound rules shard ``heads`` but not ``kv_heads``, and
+    otherwise reads the cache once per kv head.
     """
     K = k.shape[2]
     if K == H:
@@ -455,6 +458,16 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     q [B,1,H,D]; caches [B,Smax,K,D]; cache_len: valid prefix (includes the
     token just written).  Softmax over the padded axis is masked.
 
+    Grouped-query form (scope ``gqa_grouped``): q is viewed as
+    [B,1,K,H//K,D] and contracted against the caches as stored, so each
+    kv head's bf16 cache is read once for its H // K query heads, never
+    repeated to H heads.  Head h reads kv head h // (H // K), as
+    ``jnp.repeat`` maps it.  Taken with no rules bound (the serve engine on
+    one chip) or with ``kv_heads`` sharded.  Only when the bound rules
+    shard ``heads`` but not ``kv_heads`` (TP > K) are the caches repeated
+    to H heads first, the Megatron treatment of ``repeat_kv`` (scope
+    ``gqa_repeated``).  With K == H both forms are the same work.
+
     Cache-dtype-native: scores/outputs accumulate in fp32 via
     ``preferred_element_type`` but the cache operands are NEVER converted —
     a ``cache.astype(f32)`` here gets hoisted out of the layer scan by
@@ -470,32 +483,39 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     layer 0, 0.41 logit error downstream).
     """
     B, _, H, D = q.shape
-    # barrier: without it, the CPU backend legalizes the bf16 dot below as
-    # convert(f32)+dot, and LICM hoists the convert of the *whole stacked
-    # cache* out of the layer scan (+12 GiB/device observed).  On TPU the
-    # dot is native bf16 and the barrier is free.
-    k_cache, v_cache = lax.optimization_barrier((k_cache, v_cache))
-    kr = repeat_kv(k_cache, H)
-    vr = repeat_kv(v_cache, H)
-    qs = q.astype(kr.dtype) * jnp.asarray(1.0 / math.sqrt(D), kr.dtype)
-    s = jnp.einsum("bqhd,bshd->bhqs", qs, kr,
-                   preferred_element_type=jnp.float32)
-    s = constrain(s, "batch", "heads", None, None)
+    rules = active_rules() or {}
+    repeated = (rules.get("heads") is not None
+                and rules.get("kv_heads") is None)
     cl = jnp.asarray(cache_len)
     if cl.ndim == 1:                      # ragged: per-row valid prefix [B]
-        cl = cl[:, None, None, None]
-    mask = jnp.arange(kr.shape[1])[None, None, None, :] < cl
-    s = jnp.where(mask, s, -jnp.inf)
-    m = jnp.max(s, axis=-1)
-    m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
-    p = jnp.exp(s - m_safe[..., None]).astype(vr.dtype)
-    p = jnp.where(jnp.isneginf(s), 0.0, p)
-    l = jnp.sum(p.astype(jnp.float32), axis=-1)
-    pv = jnp.einsum("bhqs,bshd->bhqd", p, vr,
-                    preferred_element_type=jnp.float32)
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o = jnp.transpose(pv / l_safe[..., None], (0, 2, 1, 3))
-    return o.astype(q.dtype)
+        cl = cl[:, None, None, None, None]
+    with jax.named_scope("gqa_repeated" if repeated else "gqa_grouped"):
+        # barrier: without it, the CPU backend legalizes the bf16 dot below
+        # as convert(f32)+dot, and LICM hoists the convert of the *whole
+        # stacked cache* out of the layer scan (+12 GiB/device observed).
+        # On TPU the dot is native bf16 and the barrier is free.
+        k_cache, v_cache = lax.optimization_barrier((k_cache, v_cache))
+        if repeated:
+            k_cache, v_cache = repeat_kv(k_cache, H), repeat_kv(v_cache, H)
+        S, Kv = k_cache.shape[1], k_cache.shape[2]
+        qs = q.astype(k_cache.dtype) * jnp.asarray(1.0 / math.sqrt(D),
+                                                   k_cache.dtype)
+        qg = qs.reshape(B, 1, Kv, H // Kv, D)
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k_cache,
+                       preferred_element_type=jnp.float32)
+        s = constrain(s, "batch", "heads" if repeated else "kv_heads",
+                      None, None, None)
+        s = jnp.where(jnp.arange(S) < cl, s, -jnp.inf)
+        m = jnp.max(s, axis=-1)
+        m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
+        p = jnp.exp(s - m_safe[..., None]).astype(v_cache.dtype)
+        p = jnp.where(jnp.isneginf(s), 0.0, p)
+        l = jnp.sum(p.astype(jnp.float32), axis=-1)
+        pv = jnp.einsum("bkgqs,bskd->bkgqd", p, v_cache,
+                        preferred_element_type=jnp.float32)
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o = jnp.transpose(pv / l_safe[..., None], (0, 3, 1, 2, 4))
+        return o.reshape(B, 1, H, D).astype(q.dtype)
 
 
 @jax.named_scope("attention")

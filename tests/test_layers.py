@@ -41,6 +41,47 @@ def test_flash_grads_match_naive():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+def _decode_attention_repeated(q, k_cache, v_cache, cache_len):
+    """Decode attention over the caches repeated to H heads: the form
+    ``decode_attention`` had before it grouped the query heads."""
+    B, _, H, D = q.shape
+    k_cache, v_cache = jax.lax.optimization_barrier((k_cache, v_cache))
+    kr, vr = L.repeat_kv(k_cache, H), L.repeat_kv(v_cache, H)
+    qs = q.astype(kr.dtype) * jnp.asarray(1.0 / np.sqrt(D), kr.dtype)
+    s = jnp.einsum("bqhd,bshd->bhqs", qs, kr,
+                   preferred_element_type=jnp.float32)
+    mask = jnp.arange(kr.shape[1]) < cache_len[:, None, None, None]
+    s = jnp.where(mask, s, -jnp.inf)
+    m = jnp.max(s, axis=-1)
+    m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
+    p = jnp.exp(s - m_safe[..., None]).astype(vr.dtype)
+    p = jnp.where(jnp.isneginf(s), 0.0, p)
+    l = jnp.sum(p.astype(jnp.float32), axis=-1)
+    pv = jnp.einsum("bhqs,bshd->bhqd", p, vr,
+                    preferred_element_type=jnp.float32)
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o = jnp.transpose(pv / l_safe[..., None], (0, 2, 1, 3))
+    return o.astype(q.dtype)
+
+
+@pytest.mark.parametrize("H,K", [(16, 8), (8, 1), (4, 4)])
+def test_decode_attention_grouped_equals_repeated(H, K):
+    """Reading each kv head's bf16 cache once for its H // K query heads
+    gives the repeated form's output bit for bit (ragged prefixes, the
+    shortest and the full one among them)."""
+    S, D = 64, 32
+    ks = jax.random.split(jax.random.PRNGKey(H * 10 + K), 3)
+    q = jax.random.normal(ks[0], (4, 1, H, D), jnp.bfloat16)
+    k_cache = jax.random.normal(ks[1], (4, S, K, D), jnp.bfloat16)
+    v_cache = jax.random.normal(ks[2], (4, S, K, D), jnp.bfloat16)
+    cache_len = jnp.asarray([1, 17, S - 1, S], jnp.int32)
+    out = jax.jit(L.decode_attention)(q, k_cache, v_cache, cache_len)
+    ref = jax.jit(_decode_attention_repeated)(q, k_cache, v_cache, cache_len)
+    assert out.dtype == ref.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(ref, np.float32))
+
+
 @given(st.integers(1, 4), st.integers(8, 48), st.integers(1, 3))
 @settings(max_examples=8, deadline=None)
 def test_ssd_chunked_equals_reference(b, l, h):

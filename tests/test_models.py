@@ -39,7 +39,7 @@ def test_smoke_forward_loss(arch):
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "qwen3-1.7b",
                                   "deepseek-moe-16b", "mamba2-780m",
                                   "jamba-v0.1-52b", "whisper-small",
-                                  "qwen2-vl-2b"])
+                                  "qwen2-vl-2b", "internlm2-1.8b"])
 def test_decode_matches_teacher_forcing(arch):
     """prefill+decode must reproduce the teacher-forced logits."""
     cfg = get_config(arch).reduced().override(moe_capacity_factor=8.0)
@@ -60,6 +60,54 @@ def test_decode_matches_teacher_forcing(arch):
     np.testing.assert_allclose(np.asarray(ld[:, 0], np.float32),
                                np.asarray(full[:, S - 1], np.float32),
                                atol=5e-2, rtol=1e-2)
+
+
+def _jaxpr_shapes(jaxpr):
+    """Shapes of every intermediate of a jaxpr, scan and jit bodies too."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield tuple(getattr(v.aval, "shape", ()))
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list))
+                        else (param,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _jaxpr_shapes(sub)
+
+
+@pytest.mark.parametrize("arch,kv_heads", [("internlm2-1.8b", None),
+                                           ("deepseek-moe-16b", 4)])
+def test_ragged_decode_reads_gqa_cache_once_per_kv_head(arch, kv_heads):
+    """With no rules bound (the serve engine on one chip) the ragged decode
+    step reads each kv head's cache as stored: no intermediate holds both
+    the query-head count and the cache length when K < H, none holds the
+    cache's S*K rows as one axis (a view that would score every query head
+    against every kv head), and the compiled program carries the
+    ``gqa_grouped`` scope.  deepseek-moe-16b runs K == H, as published."""
+    from repro.models import transformer
+    cfg = get_config(arch).reduced().override(num_layers=3)
+    if kv_heads:
+        cfg = cfg.override(num_kv_heads=kv_heads)
+    B, S = 2, 40
+    H, K = cfg.num_heads, cfg.num_kv_heads
+    assert S not in (B, H, K, cfg.hd, cfg.num_layers)
+    api = build(cfg)
+    params = jax.eval_shape(api.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: api.init_cache(B, S))
+    cache["pos"] = jax.ShapeDtypeStruct((B,), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+
+    def step(p, t, c):
+        return transformer.decode_step_ragged(cfg, p, t, c)
+
+    jaxpr = jax.make_jaxpr(step)(params, tokens, cache).jaxpr
+    shapes = set(_jaxpr_shapes(jaxpr))
+    assert not [sh for sh in shapes if S * K in sh]
+    if K < H:
+        assert not [sh for sh in shapes if H in sh and S in sh]
+    text = jax.jit(step).lower(params, tokens, cache).as_text(
+        debug_info=True)
+    assert "gqa_grouped" in text and "gqa_repeated" not in text
 
 
 def test_mrope_collapses_to_rope_for_text():

@@ -111,6 +111,53 @@ def test_moe_shard_map_matches_reference():
     assert "OK" in out
 
 
+def test_decode_gqa_path_follows_the_head_rules():
+    """On a 4-way model axis the rules shard ``heads`` (4) but not
+    ``kv_heads`` (2): decode repeats the cache (``gqa_repeated``).  On a
+    2-way axis both are sharded and decode reads each kv head's cache as
+    stored (``gqa_grouped``).  Both match the ragged decode with no rules
+    bound."""
+    out = run_sub("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.models import build, get_config, transformer
+        from repro.distributed.logical import default_rules, logical_rules
+        from repro.launch.mesh import make_mesh
+
+        cfg = get_config("internlm2-1.8b").reduced().override(num_layers=2)
+        assert (cfg.num_heads, cfg.num_kv_heads) == (4, 2)
+        api = build(cfg)
+        params = api.init(jax.random.PRNGKey(0))
+        B, S = 4, 32
+        cache = api.init_cache(B, S)
+        ks = jax.random.split(jax.random.PRNGKey(1), 3)
+        cache["k"] = jax.random.normal(ks[0], cache["k"].shape,
+                                       cache["k"].dtype)
+        cache["v"] = jax.random.normal(ks[1], cache["v"].shape,
+                                       cache["v"].dtype)
+        cache["pos"] = jnp.asarray([0, 5, 17, S - 1], jnp.int32)
+        tokens = jax.random.randint(ks[2], (B, 1), 0, cfg.vocab_size)
+
+        def step(p, t, c):
+            return transformer.decode_step_ragged(cfg, p, t, c)
+
+        ref, _ = jax.jit(step)(params, tokens, cache)
+        for model, scope in ((4, "gqa_repeated"), (2, "gqa_grouped")):
+            mesh = make_mesh((4 // model, model), ("data", "model"))
+            with mesh, logical_rules(default_rules(cfg, mesh)):
+                lowered = jax.jit(step).lower(params, tokens, cache)
+                text = lowered.as_text(debug_info=True)
+                assert scope in text, (model, scope)
+                other = ({"gqa_repeated", "gqa_grouped"} - {scope}).pop()
+                assert other not in text, (model, other)
+                got, _ = lowered.compile()(params, tokens, cache)
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(ref, np.float32),
+                                       atol=2e-2, rtol=0)
+        print("OK")
+    """, devices=4)
+    assert "OK" in out
+
+
 @pytest.mark.slow
 def test_elastic_restore_reshard(tmp_path=None):
     """Save sharded on 8 devices, restore onto a 4-device mesh."""

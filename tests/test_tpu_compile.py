@@ -6,10 +6,14 @@ described ``v5e:2x2`` topology: the TPU compiler is installed even where
 no chip is attached.  A kernel that only interpret mode accepts (a block
 not aligned to the TPU tiling, more scoped VMEM than the limit) fails
 here, with no chip time spent.  Nothing runs; the compiled program must
-contain the Mosaic kernel (``tpu_custom_call``).
+contain the Mosaic kernel (``tpu_custom_call``).  The serve engine's
+decode step is compiled the same way, to read what XLA makes of its
+attention over the KV cache.
 """
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +29,7 @@ from repro.kernels.rmsnorm import ops as rmsnorm_ops
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.ssd_scan.kernel import ssd_chunk_pallas
+from repro.models import build, get_config, transformer
 
 
 @pytest.fixture(scope="module")
@@ -151,3 +156,46 @@ def test_run_stage_kernel_shapes_compile(one_chip, family):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     fn, *args = RUN_STAGE_CASES[family](f32)
     assert "tpu_custom_call" in _compile_text(fn, *args)
+
+
+_HLO_ARRAY = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = (\w+)\[([\d,]*)\]\S* ([a-z][\w-]*)\(")
+
+
+def test_decode_step_reads_the_kv_cache_as_stored(one_chip):
+    """internlm2-1.8b's ragged decode step at its served widths (16 query
+    heads over 8 kv heads, 16 slots x 2,048 positions): no cache-sized
+    float32 array (a widened cache) and no cache-sized broadcast (a cache
+    repeated to the query heads) in the compiled program, whose attention
+    runs under the ``gqa_grouped`` scope.  A bf16 copy of a layer's cache
+    is allowed: XLA transposes it to [B,K,S,D] for the dot batched over
+    (batch, kv head)."""
+    cfg = get_config("internlm2-1.8b").override(num_layers=2)
+    api = build(cfg)
+    B, S = 16, 2048
+
+    def shaped(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
+                                    sharding=one_chip)
+
+    params = jax.tree.map(lambda a: shaped(a, jnp.bfloat16),
+                          jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    cache = jax.tree.map(shaped, jax.eval_shape(
+        lambda: api.init_cache(B, S, jnp.bfloat16)))
+    cache["pos"] = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    text = _compile_text(
+        lambda p, t, c: transformer.decode_step_ragged(cfg, p, t, c),
+        params, tokens, cache)
+    layer_cache = B * S * cfg.num_kv_heads * cfg.hd
+    offending = []
+    for line in text.splitlines():
+        m = _HLO_ARRAY.match(line)
+        if not m:
+            continue
+        dtype, dims, op = m.groups()
+        if math.prod(int(d) for d in dims.split(",") if d) < layer_cache:
+            continue
+        if dtype == "f32" or op == "broadcast":
+            offending.append(line.strip()[:160])
+    assert not offending, offending
+    assert "gqa_grouped" in text
