@@ -1,8 +1,9 @@
 """mamba2-780m — SSD (state-space duality) [arXiv:2405.21060; unverified].
 
-48L d_model=1536 attention-free, vocab 50280, ssm_state=128; expand=2 →
-d_inner=3072, head_dim 64 → 48 SSD heads, 1 group, conv4.  Sub-quadratic:
-runs the long_500k shape.
+48L d_model=1536 attention-free, vocab 50277 padded to a multiple of 16
+(50288 rows, tied), ssm_state=128; expand=2 → d_inner=3072, head_dim 64 →
+48 SSD heads, 1 group, conv4, RMSNorm eps 1e-5 (huggingface.co/
+state-spaces/mamba2-780m).  Sub-quadratic: runs the long_500k shape.
 """
 from repro.models.config import ModelConfig, register_arch
 
@@ -15,7 +16,8 @@ CONFIG = register_arch(ModelConfig(
     num_kv_heads=1,
     head_dim=64,
     d_ff=0,
-    vocab_size=50280,
+    vocab_size=50288,
+    norm_eps=1e-5,
     tie_embeddings=True,
     ssm_state=128,
     ssm_expand=2,

@@ -89,7 +89,7 @@ def param_rule(cfg, name: str, shape: Tuple[int, ...], mesh: Mesh) -> P:
     if "embed" in name and leaf == "table":
         if div(-2):                      # vocab
             return _spec_with(nd, -2, "model")
-        # non-divisible vocab (whisper 51865, mamba2 50280): replicate.
+        # non-divisible vocab (whisper 51865): replicate.
         # Sharding d_model instead trips the SPMD partitioner on the
         # token-gather inside the microbatch loop (observed: whisper
         # train_4k, "slice dim size 768 > dynamic slice dimension 48").
@@ -127,11 +127,12 @@ def param_rule(cfg, name: str, shape: Tuple[int, ...], mesh: Mesh) -> P:
     # mamba2
     if leaf in ("w_z", "w_x"):
         return _spec_with(nd, -1, "model") if div(-1) else P()
-    if leaf in ("w_B", "w_C", "conv_B", "conv_C"):
+    if leaf in ("w_B", "w_C", "conv_B", "conv_C", "conv_B_bias",
+                "conv_C_bias"):
         return P()
     if leaf == "w_dt":
         return _spec_with(nd, -1, "model") if div(-1) else P()
-    if leaf == "conv_x":
+    if leaf in ("conv_x", "conv_x_bias"):
         return _spec_with(nd, -1, "model") if div(-1) else P()
     if leaf in ("A_log", "D", "dt_bias"):
         return _spec_with(nd, -1, "model") if div(-1) else P()

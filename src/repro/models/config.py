@@ -127,7 +127,7 @@ class ModelConfig:
         di, N, G = self.ssm_d_inner, self.ssm_state, self.ssm_groups
         nheads = self.ssm_heads if self.ssm_state else 0
         ssm = (d * (2 * di + 2 * G * N + nheads)          # in_proj
-               + self.ssm_conv * (di + 2 * G * N)         # depthwise conv
+               + (self.ssm_conv + 1) * (di + 2 * G * N)   # depthwise conv, bias
                + nheads * 2                               # A_log, D
                + nheads                                   # dt_bias
                + di                                       # gated norm

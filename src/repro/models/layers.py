@@ -820,6 +820,17 @@ def moe_layer(p: Params, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
 # Mamba2 / SSD block
 # ---------------------------------------------------------------------------
 
+#: The published ``Mamba2`` module's settings (state-spaces/mamba,
+#: arXiv:2405.21060) that ``init_mamba2`` and ``mamba2_block`` follow:
+#: A drawn uniform in ``A_init_range``; dt log-uniform in
+#: [``dt_min``, ``dt_max``], floored at ``dt_init_floor``, stored as its
+#: softplus inverse; a bias on the conv over x, B and C and none on the
+#: projections; D one scalar a head; the gated norm after the gate.
+MAMBA2_MODULE = {"A_init_range": [1.0, 16.0], "dt_min": 1e-3, "dt_max": 0.1,
+                 "dt_init_floor": 1e-4, "conv_bias": True, "bias": False,
+                 "D_has_hdim": False, "norm_before_gate": False}
+
+
 def init_mamba2(key, cfg) -> Params:
     """Mamba2 weights with *split* projections.
 
@@ -828,30 +839,48 @@ def init_mamba2(key, cfg) -> Params:
     fused layouts concatenate segments whose boundaries are not divisible
     by the 16-way model axis, which would force full replication under TP.
     Split weights let d_inner shard cleanly (see distributed/partition.py).
+    A_log, dt_bias, D and the conv biases are drawn as upstream draws them
+    (``MAMBA2_MODULE``; the biases as torch's Conv1d default,
+    U(±1/sqrt(d_conv))).
     """
     d, di = cfg.d_model, cfg.ssm_d_inner
     H, N, G = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_groups
-    ks = jax.random.split(key, 9)
+    k = cfg.ssm_conv
+    m = MAMBA2_MODULE
+    ks = jax.random.split(key, 14)
+    lo, hi = m["A_init_range"]
+    A = jax.random.uniform(ks[12], (H,), jnp.float32, lo, hi)
+    log_dt = jax.random.uniform(ks[13], (H,), jnp.float32,
+                                math.log(m["dt_min"]), math.log(m["dt_max"]))
+    dt = jnp.maximum(jnp.exp(log_dt), m["dt_init_floor"])
+    bound = 1.0 / math.sqrt(k)
     return {
         "w_z": dense_init(ks[0], (d, di)),
         "w_x": dense_init(ks[1], (d, di)),
         "w_B": dense_init(ks[2], (d, G * N)),
         "w_C": dense_init(ks[3], (d, G * N)),
         "w_dt": dense_init(ks[4], (d, H)),
-        "conv_x": dense_init(ks[5], (cfg.ssm_conv, di), scale=0.5),
-        "conv_B": dense_init(ks[6], (cfg.ssm_conv, G * N), scale=0.5),
-        "conv_C": dense_init(ks[7], (cfg.ssm_conv, G * N), scale=0.5),
-        "A_log": jnp.log(jnp.linspace(1.0, 16.0, H, dtype=jnp.float32)),
+        "conv_x": dense_init(ks[5], (k, di), scale=0.5),
+        "conv_B": dense_init(ks[6], (k, G * N), scale=0.5),
+        "conv_C": dense_init(ks[7], (k, G * N), scale=0.5),
+        "conv_x_bias": jax.random.uniform(ks[9], (di,), jnp.float32,
+                                          -bound, bound),
+        "conv_B_bias": jax.random.uniform(ks[10], (G * N,), jnp.float32,
+                                          -bound, bound),
+        "conv_C_bias": jax.random.uniform(ks[11], (G * N,), jnp.float32,
+                                          -bound, bound),
+        "A_log": jnp.log(A),
         "D": jnp.ones((H,), jnp.float32),
-        "dt_bias": jnp.zeros((H,), jnp.float32),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),     # softplus^-1(dt)
         "norm": init_rmsnorm(di),
         "out_proj": dense_init(ks[8], (di, d)),
     }
 
 
-def causal_conv1d(w: jax.Array, x: jax.Array,
+def causal_conv1d(w: jax.Array, bias: jax.Array, x: jax.Array,
                   tail: Optional[jax.Array] = None) -> jax.Array:
-    """Depthwise causal conv via shift-and-sum.  w [k, C]; x [B, S, C].
+    """Depthwise causal conv via shift-and-sum, plus bias, then SiLU.
+    w [k, C]; bias [C]; x [B, S, C].
 
     ``tail``: [B, k-1, C] carry-in from previous tokens (decode path).
     """
@@ -863,7 +892,7 @@ def causal_conv1d(w: jax.Array, x: jax.Array,
     out = jnp.zeros_like(x, dtype=jnp.float32)
     for i in range(k):
         out = out + xp[:, i:i + S].astype(jnp.float32) * w[i]
-    return jax.nn.silu(out).astype(x.dtype)
+    return jax.nn.silu(out + bias.astype(jnp.float32)).astype(x.dtype)
 
 
 def ssd_reference(x, dt, A, B, C, D, *, init_state=None):
@@ -894,6 +923,7 @@ def ssd_reference(x, dt, A, B, C, D, *, init_state=None):
     return y.astype(x.dtype), hfin
 
 
+@jax.named_scope("ssd")
 def ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 128, init_state=None):
     """Chunked SSD (state-space duality) — the parallel production path.
 
@@ -927,9 +957,13 @@ def ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 128, init_state=None):
 
     # intra-chunk: y_q += sum_{k<=q} exp(a_cs_q - a_cs_k) (C_q·B_k) dt_k x_k
     cb = jnp.einsum("bcqn,bckn->bcqk", Cf, Bf)       # [b,nc,Q,Q]
-    decay = jnp.exp(a_cs[:, :, :, None, :] - a_cs[:, :, None, :, :])
+    # the segment sums are masked to -inf above the diagonal before exp, as
+    # upstream's segsum: there they are large and positive, and an exp that
+    # overflowed to inf would meet the mask's zero in the backward pass
+    # (inf * 0 = NaN)
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.where(mask[None, None, :, :, None], decay, 0.0)
+    seg = a_cs[:, :, :, None, :] - a_cs[:, :, None, :, :]
+    decay = jnp.exp(jnp.where(mask[None, None, :, :, None], seg, -jnp.inf))
     w = cb[..., None] * decay                        # [b,nc,Q,Q,h]
     y_intra = jnp.einsum("bcqkh,bckh,bckhp->bcqhp", w, dtf, xf)
 
@@ -959,6 +993,7 @@ def ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 128, init_state=None):
     return y.astype(x.dtype), hfin
 
 
+@jax.named_scope("mixer")
 def mamba2_block(p: Params, x: jax.Array, cfg, *,
                  ssm_state=None, conv_tail=None, return_state: bool = False):
     """Full Mamba2 sublayer.  x [B,S,d] → y [B,S,d] (+ cache updates).
@@ -978,9 +1013,9 @@ def mamba2_block(p: Params, x: jax.Array, cfg, *,
     new_tail = ({"x": xin[:, -km1:], "B": Bc[:, -km1:], "C": Cc[:, -km1:]}
                 if return_state else None)
     tails = conv_tail or {"x": None, "B": None, "C": None}
-    xin = causal_conv1d(p["conv_x"], xin, tail=tails["x"])
-    Bc = causal_conv1d(p["conv_B"], Bc, tail=tails["B"])
-    Cc = causal_conv1d(p["conv_C"], Cc, tail=tails["C"])
+    xin = causal_conv1d(p["conv_x"], p["conv_x_bias"], xin, tail=tails["x"])
+    Bc = causal_conv1d(p["conv_B"], p["conv_B_bias"], Bc, tail=tails["B"])
+    Cc = causal_conv1d(p["conv_C"], p["conv_C_bias"], Cc, tail=tails["C"])
 
     xh = constrain(xin.reshape(B_, S, H, P), "batch", None, "ssm_heads",
                    None)
@@ -998,13 +1033,13 @@ def mamba2_block(p: Params, x: jax.Array, cfg, *,
     return out
 
 
-def _conv_decode(w: jax.Array, tail: jax.Array, new: jax.Array
-                 ) -> Tuple[jax.Array, jax.Array]:
+def _conv_decode(w: jax.Array, bias: jax.Array, tail: jax.Array,
+                 new: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """One-token depthwise conv: (out [B,1,C], new_tail [B,k-1,C])."""
     full = jnp.concatenate([tail, new], axis=1)             # [B,k,C]
     out = jax.nn.silu(
         jnp.sum(full.astype(jnp.float32) * w[None], axis=1, keepdims=True)
-    ).astype(new.dtype)
+        + bias.astype(jnp.float32)).astype(new.dtype)
     return out, full[:, 1:]
 
 
@@ -1016,11 +1051,11 @@ def mamba2_decode_step(p: Params, x: jax.Array, cfg, *,
     N, G, P = cfg.ssm_state, cfg.ssm_groups, cfg.ssm_head_dim
     z = x @ p["w_z"].astype(x.dtype)
     dt_raw = x @ p["w_dt"].astype(x.dtype)
-    xin, tail_x = _conv_decode(p["conv_x"], conv_tail["x"],
+    xin, tail_x = _conv_decode(p["conv_x"], p["conv_x_bias"], conv_tail["x"],
                                x @ p["w_x"].astype(x.dtype))
-    Bc, tail_B = _conv_decode(p["conv_B"], conv_tail["B"],
+    Bc, tail_B = _conv_decode(p["conv_B"], p["conv_B_bias"], conv_tail["B"],
                               x @ p["w_B"].astype(x.dtype))
-    Cc, tail_C = _conv_decode(p["conv_C"], conv_tail["C"],
+    Cc, tail_C = _conv_decode(p["conv_C"], p["conv_C_bias"], conv_tail["C"],
                               x @ p["w_C"].astype(x.dtype))
     new_tail = {"x": tail_x, "B": tail_B, "C": tail_C}
 

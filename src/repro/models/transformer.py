@@ -267,6 +267,12 @@ def decode_step_ragged(cfg: ModelConfig, params: Params, tokens: jax.Array,
     slots hold requests admitted at different times; the uniform-batch
     ``decode_step`` remains the production multi-pod path (per-row scatter
     onto a sequence-sharded cache would defeat the cache sharding).
+
+    The stacked caches ride the layer scan's carry and each layer scatters
+    its new keys and values into them in place (as the scan's sliced input
+    and stacked output instead, every layer's slab would be written back
+    whole each step).  The serve engine donates the cache, so nothing
+    copies it.
     """
     B = tokens.shape[0]
     pos = cache["pos"]                                   # [B]
@@ -276,8 +282,9 @@ def decode_step_ragged(cfg: ModelConfig, params: Params, tokens: jax.Array,
     if cfg.mrope_sections:
         positions = jnp.broadcast_to(positions[None], (3, B, 1))
 
-    def block(x, inp):
-        p, k_c, v_c = inp
+    def block(carry, inp):
+        x, k_all, v_all = carry
+        p, i = inp
         h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
         with jax.named_scope("attention"):
             q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
@@ -286,9 +293,9 @@ def decode_step_ragged(cfg: ModelConfig, params: Params, tokens: jax.Array,
                              cfg.mrope_sections, cfg.use_rope)
             k = L.apply_rope(k, positions, cfg.rope_theta,
                              cfg.mrope_sections, cfg.use_rope)
-            k_c = k_c.at[bidx, pos].set(k[:, 0].astype(k_c.dtype))
-            v_c = v_c.at[bidx, pos].set(v[:, 0].astype(v_c.dtype))
-            o = L.decode_attention(q, k_c, v_c, pos + 1)
+            k_all = k_all.at[i, bidx, pos].set(k[:, 0].astype(k_all.dtype))
+            v_all = v_all.at[i, bidx, pos].set(v[:, 0].astype(v_all.dtype))
+            o = L.decode_attention(q, k_all[i], v_all[i], pos + 1)
             o = o.reshape(B, 1, cfg.num_heads * cfg.hd) @ \
                 p["attn"]["wo"].astype(x.dtype)
         x = x + o
@@ -297,10 +304,11 @@ def decode_step_ragged(cfg: ModelConfig, params: Params, tokens: jax.Array,
             m, _ = L.moe_layer(p["moe"], h, cfg)
         else:
             m = L.mlp(p["mlp"], h, cfg.act)
-        return x + m, (k_c, v_c)
+        return (x + m, k_all, v_all), None
 
-    x, (k_new, v_new) = lax.scan(
-        block, x, (params["blocks"], cache["k"], cache["v"]))
+    (x, k_new, v_new), _ = lax.scan(
+        block, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(cache["k"].shape[0])))
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     out = L.unembed(unembed_table(params), x, jnp.dtype(cfg.logits_dtype))
     return out, {"k": k_new, "v": v_new, "pos": pos + 1}

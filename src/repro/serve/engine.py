@@ -136,8 +136,11 @@ class ServeEngine:
 
         def decode_step(p, t, c):
             return transformer.decode_step_ragged(api.cfg, p, t, c)
-        # a named function: the trace calls the program jit_decode_step
-        self._decode = jax.jit(decode_step)
+        # a named function: the trace calls the program jit_decode_step.
+        # The pool cache is donated to the decode step and to the splice,
+        # which update it in place: the engine holds no other reference
+        self._decode = jax.jit(decode_step, donate_argnums=(2,))
+        self._splice = jax.jit(_splice_row, donate_argnums=(0,))
         self._prefill_cache = {}
         # host-side per-slot position clocks (prefix + decoded tokens):
         # max_len exhaustion is a host decision, it must not force the
@@ -261,7 +264,7 @@ class ServeEngine:
             tok = int(jnp.argmax(logits_row[0, -1]))
         req.output.append(tok)
         with span("engine.splice", spans):
-            self.cache = _splice_row(self.cache, row_cache, slot)
+            self.cache = self._splice(self.cache, row_cache, slot)
         self._slot_pos[slot] = n
         self._pending_tok = getattr(self, "_pending_tok",
                                     np.zeros(self.cfg.max_batch, np.int32))
@@ -333,8 +336,9 @@ class ServeEngine:
                 "throughput_tok_s": toks / span if span > 0 else 0.0}
 
 
-def _splice_row(pool_cache, row_cache, slot: int):
-    """Copy a 1-row cache into slot ``slot`` of the pool cache.
+def _splice_row(pool_cache, row_cache, slot):
+    """Copy a 1-row cache into slot ``slot`` (an int or a traced scalar:
+    one compiled splice serves every slot) of the pool cache.
 
     Batch dim differs by cache kind: [L,B,...] arrays have it at axis 1,
     hybrid ssm entries at axis 2; 'pos' is a scalar (shared clock — per
@@ -362,8 +366,7 @@ def _splice_row(pool_cache, row_cache, slot: int):
         # find the batch axis: first axis where sizes differ
         for ax in range(1, pool.ndim):
             if row.shape[ax] == 1 and pool.shape[ax] > 1:
-                idx = [slice(None)] * pool.ndim
-                idx[ax] = slice(slot, slot + 1)
-                return pool.at[tuple(idx)].set(row)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    pool, row.astype(pool.dtype), slot, axis=ax)
         return pool
     return jax.tree_util.tree_map(splice, pool_cache, row_cache)

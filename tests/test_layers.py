@@ -101,6 +101,78 @@ def test_ssd_chunked_equals_reference(b, l, h):
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=3e-5)
 
 
+def _ssd_inputs(chunk, dt_kind, b=1, h=4, p=8, n=16):
+    """Two chunks of inputs with A in [-16, -1] (the published A_init_range)
+    and dt either over the published initial range, log-uniform in
+    [0.001, 0.1], or softplus(0) (a dt_bias of zero)."""
+    l = 2 * chunk
+    ks = jax.random.split(jax.random.PRNGKey(chunk), 5)
+    x = jax.random.normal(ks[0], (b, l, h, p))
+    A = -jax.random.uniform(ks[1], (h,), minval=1.0, maxval=16.0)
+    if dt_kind == "softplus0":
+        dt = jnp.full((b, l, h), jax.nn.softplus(0.0))
+    else:
+        dt = jnp.exp(jax.random.uniform(ks[2], (b, l, h),
+                                        minval=np.log(1e-3),
+                                        maxval=np.log(0.1)))
+    Bm = jax.random.normal(ks[3], (b, l, 1, n)) * 0.3
+    Cm = jax.random.normal(ks[4], (b, l, 1, n)) * 0.3
+    return x, dt, A, Bm, Cm, jnp.ones((h,))
+
+
+@pytest.mark.parametrize("chunk", [128, 256])
+@pytest.mark.parametrize("dt_kind", ["published", "softplus0"])
+def test_ssd_chunked_gradients_are_finite(chunk, dt_kind):
+    """Above the diagonal the intra-chunk segment sums are large and
+    positive; masked after exp they overflowed to inf and the backward
+    pass read inf * 0 = NaN."""
+    x, dt, A, Bm, Cm, D = _ssd_inputs(chunk, dt_kind)
+
+    def f(x, dt, A, Bm, Cm):
+        y, s = L.ssd_chunked(x, dt, A, Bm, Cm, D, chunk=chunk)
+        return jnp.sum(y ** 2) + jnp.sum(s ** 2)
+
+    grads = jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4)))(x, dt, A, Bm, Cm)
+    for g in grads:
+        assert np.all(np.isfinite(np.asarray(g)))
+
+
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_ssd_chunked_forward_matches_recurrence_at_published_dt(chunk):
+    x, dt, A, Bm, Cm, D = _ssd_inputs(chunk, "published")
+    y1, s1 = L.ssd_reference(x, dt, A, Bm, Cm, D)
+    y2, s2 = L.ssd_chunked(x, dt, A, Bm, Cm, D, chunk=chunk)
+    np.testing.assert_allclose(np.asarray(y2), np.asarray(y1), atol=1e-6,
+                               rtol=0)
+    np.testing.assert_allclose(np.asarray(s2), np.asarray(s1), atol=1e-6,
+                               rtol=0)
+
+
+def test_mamba2_init_follows_the_published_module():
+    """A = exp(A_log) uniform in [1, 16]; softplus(dt_bias) log-uniform in
+    [0.001, 0.1]; D one; a bias on the conv over x, B and C within
+    ±1/sqrt(d_conv)."""
+    from repro.models import get_config
+    cfg = get_config("mamba2-780m")
+    p = L.init_mamba2(jax.random.PRNGKey(3), cfg)
+    H = cfg.ssm_heads
+    A = np.exp(np.asarray(p["A_log"]))
+    dt = np.asarray(jax.nn.softplus(p["dt_bias"]))
+    assert A.shape == dt.shape == p["D"].shape == (H,)
+    assert A.min() >= 1.0 and A.max() <= 16.0 and A.std() > 2.0
+    assert dt.min() >= 1e-3 * (1 - 1e-5) and dt.max() <= 0.1 * (1 + 1e-5)
+    log_dt = np.log(dt)
+    assert log_dt.max() - log_dt.min() > 0.6 * np.log(100.0)
+    np.testing.assert_array_equal(np.asarray(p["D"]), np.ones(H))
+    bound = 1 / np.sqrt(cfg.ssm_conv)
+    for name, width in (("conv_x_bias", cfg.ssm_d_inner),
+                        ("conv_B_bias", cfg.ssm_state),
+                        ("conv_C_bias", cfg.ssm_state)):
+        b = np.asarray(p[name])
+        assert b.shape == (width,)
+        assert np.abs(b).max() <= bound and np.abs(b).max() > 0.4 * bound
+
+
 def test_moe_scatter_equals_einsum():
     p = L.init_moe(jax.random.PRNGKey(0), 32, 8, 64, 1)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
@@ -129,19 +201,24 @@ def test_moe_capacity_invariants(T, E, k, cf):
 
 
 def test_causal_conv_matches_decode_path():
-    """Streaming conv (decode) == full conv applied position-wise."""
+    """Streaming conv (decode) == full conv applied position-wise, bias
+    included."""
     k, C = 4, 6
     w = jax.random.normal(jax.random.PRNGKey(0), (k, C)) * 0.3
+    bias = jax.random.uniform(jax.random.PRNGKey(2), (C,), minval=-0.5,
+                              maxval=0.5)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 10, C))
-    full = L.causal_conv1d(w, x)
+    full = L.causal_conv1d(w, bias, x)
     tail = jnp.zeros((2, k - 1, C))
     outs = []
     for t in range(10):
-        out, tail = L._conv_decode(w, tail, x[:, t:t + 1])
+        out, tail = L._conv_decode(w, bias, tail, x[:, t:t + 1])
         outs.append(out)
     stream = jnp.concatenate(outs, axis=1)
     np.testing.assert_allclose(np.asarray(full), np.asarray(stream),
                                atol=1e-5)
+    no_bias = L.causal_conv1d(w, jnp.zeros((C,)), x)
+    assert np.abs(np.asarray(full - no_bias)).max() > 1e-2
 
 
 def test_chunked_loss_matches_unchunked():

@@ -36,6 +36,18 @@ def test_smoke_forward_loss(arch):
     assert np.all(np.isfinite(np.asarray(logits, np.float32)))
 
 
+@pytest.mark.parametrize("arch", ["mamba2-780m", "jamba-v0.1-52b"])
+def test_ssd_families_loss_gradients_are_finite(arch):
+    """The SSD mixer's backward pass, in the SSM and the hybrid family."""
+    cfg = get_config(arch).reduced()
+    api = build(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    batch = make_batch(cfg)
+    grads = jax.jit(jax.grad(lambda p: api.loss(p, batch)[0]))(params)
+    for path, g in jax.tree_util.tree_flatten_with_path(grads)[0]:
+        assert np.all(np.isfinite(np.asarray(g, np.float32))), path
+
+
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "qwen3-1.7b",
                                   "deepseek-moe-16b", "mamba2-780m",
                                   "jamba-v0.1-52b", "whisper-small",

@@ -219,3 +219,21 @@ def test_single_slot_engine_matches_reference(small):
     eng.submit(prompt, max_tokens=6)
     done = eng.run()
     assert done[0].output == ref
+
+
+def test_engine_updates_the_pool_cache_in_place(small):
+    """The pool cache is donated to the splice and to the decode step, so
+    a step that admits and decodes leaves the previous cache's buffers
+    deleted (no second pool cache is kept), and one compiled splice
+    serves every slot."""
+    cfg, api, params = small
+    eng = ServeEngine(api, params, ServeConfig(max_batch=3, max_len=64,
+                                               prompt_buckets=(16,)))
+    before = eng.cache["k"]
+    compiled = eng._splice._cache_size()     # shared by every engine
+    for p in (np.arange(1, 6), np.arange(2, 9), np.arange(4, 7)):
+        eng.submit(p, max_tokens=3)
+    eng.step()
+    assert all(r is not None for r in eng.slots)
+    assert before.is_deleted()
+    assert eng._splice._cache_size() <= compiled + 1

@@ -196,15 +196,21 @@ def programs(small):
              for k in ("tokens", "labels")}
     with mesh, logical_rules(default_rules(cfg, mesh)):
         train = step.lower(structs, batch).compile().as_text()
+    ssm = get_config("mamba2-780m").reduced().override(
+        num_layers=2, vocab_size=128, remat="full", loss_chunk=16)
+    ssm_structs, _, _, ssm_step = sharded_train_fns(ssm, AdamWConfig(), mesh)
+    with mesh, logical_rules(default_rules(ssm, mesh)):
+        ssm_train = ssm_step.lower(ssm_structs, batch).compile().as_text()
     eng = _engine(small)
     decode = eng._decode.lower(eng.params, jnp.zeros((2, 1), jnp.int32),
                                eng.cache).compile().as_text()
-    return {"train": train, "decode": decode}
+    return {"train": train, "decode": decode, "ssm_train": ssm_train}
 
 
 @pytest.mark.parametrize("program,scopes", [
     ("train", {"embed", "attention", "mlp", "loss", "optimizer"}),
     ("decode", {"embed", "attention", "mlp", "unembed"}),
+    ("ssm_train", {"embed", "mixer", "ssd", "loss", "optimizer"}),
 ])
 def test_compiled_programs_carry_the_layer_scopes(programs, program, scopes):
     assert scopes <= _scopes(programs[program])
@@ -215,3 +221,10 @@ def test_backward_ops_carry_their_forward_scope(programs):
     assert any("transpose(jvp(loss))" in n for n in names)
     assert any(n.startswith("jit(train_step)/transpose(")
                and "/attention/" in n for n in names)
+
+
+def test_ssd_scope_nests_in_the_mixer_forward_and_backward(programs):
+    names = _op_names(programs["ssm_train"])
+    assert any("/mixer/ssd/" in n for n in names)
+    assert any(n.startswith("jit(train_step)/transpose(")
+               and "/mixer/ssd/" in n for n in names)

@@ -161,17 +161,15 @@ def test_run_stage_kernel_shapes_compile(one_chip, family):
 _HLO_ARRAY = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = (\w+)\[([\d,]*)\]\S* ([a-z][\w-]*)\(")
 
 
-def test_decode_step_reads_the_kv_cache_as_stored(one_chip):
-    """internlm2-1.8b's ragged decode step at its served widths (16 query
-    heads over 8 kv heads, 16 slots x 2,048 positions): no cache-sized
-    float32 array (a widened cache) and no cache-sized broadcast (a cache
-    repeated to the query heads) in the compiled program, whose attention
-    runs under the ``gqa_grouped`` scope.  A bf16 copy of a layer's cache
-    is allowed: XLA transposes it to [B,K,S,D] for the dot batched over
-    (batch, kv head)."""
+_SLOTS, _MAX_LEN = 16, 2048
+
+
+def _served_decode_args(one_chip):
+    """internlm2-1.8b at its served widths, 2 layers: (cfg, (params,
+    tokens, cache)) as shapes on one v5e chip, the cache as the serve
+    engine holds it (bf16, a position per slot)."""
     cfg = get_config("internlm2-1.8b").override(num_layers=2)
     api = build(cfg)
-    B, S = 16, 2048
 
     def shaped(a, dtype=None):
         return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
@@ -180,13 +178,25 @@ def test_decode_step_reads_the_kv_cache_as_stored(one_chip):
     params = jax.tree.map(lambda a: shaped(a, jnp.bfloat16),
                           jax.eval_shape(api.init, jax.random.PRNGKey(0)))
     cache = jax.tree.map(shaped, jax.eval_shape(
-        lambda: api.init_cache(B, S, jnp.bfloat16)))
-    cache["pos"] = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
-    tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+        lambda: api.init_cache(_SLOTS, _MAX_LEN, jnp.bfloat16)))
+    cache["pos"] = jax.ShapeDtypeStruct((_SLOTS,), jnp.int32,
+                                        sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((_SLOTS, 1), jnp.int32, sharding=one_chip)
+    return cfg, (params, tokens, cache)
+
+
+def test_decode_step_reads_the_kv_cache_as_stored(one_chip):
+    """internlm2-1.8b's ragged decode step at its served widths (16 query
+    heads over 8 kv heads, 16 slots x 2,048 positions): no cache-sized
+    float32 array (a widened cache) and no cache-sized broadcast (a cache
+    repeated to the query heads) in the compiled program, whose attention
+    runs under the ``gqa_grouped`` scope.  A bf16 copy of a layer's cache
+    is allowed: XLA transposes it to [B,K,S,D] for the dot batched over
+    (batch, kv head)."""
+    cfg, args = _served_decode_args(one_chip)
     text = _compile_text(
-        lambda p, t, c: transformer.decode_step_ragged(cfg, p, t, c),
-        params, tokens, cache)
-    layer_cache = B * S * cfg.num_kv_heads * cfg.hd
+        lambda p, t, c: transformer.decode_step_ragged(cfg, p, t, c), *args)
+    layer_cache = _SLOTS * _MAX_LEN * cfg.num_kv_heads * cfg.hd
     offending = []
     for line in text.splitlines():
         m = _HLO_ARRAY.match(line)
@@ -199,3 +209,30 @@ def test_decode_step_reads_the_kv_cache_as_stored(one_chip):
             offending.append(line.strip()[:160])
     assert not offending, offending
     assert "gqa_grouped" in text
+
+
+def test_decode_step_updates_the_kv_cache_in_place(one_chip):
+    """The ragged decode step with its cache donated, as the serve engine
+    compiles it: the output cache aliases the input, and no operation the
+    size of the stacked cache copies it or writes a layer's slab back into
+    it (a scan over the cache as its sliced input and stacked output
+    does); the new tokens' keys and values are scattered in place."""
+    cfg, args = _served_decode_args(one_chip)
+    compiled = jax.jit(
+        lambda p, t, c: transformer.decode_step_ragged(cfg, p, t, c),
+        donate_argnums=(2,)).lower(*args).compile()
+    stacked = cfg.num_layers * _SLOTS * _MAX_LEN * cfg.num_kv_heads * cfg.hd
+    kv_bytes = 2 * stacked * jnp.dtype(jnp.bfloat16).itemsize
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+    ops = []
+    for line in compiled.as_text().splitlines():
+        m = _HLO_ARRAY.match(line)
+        if m and math.prod(int(d) for d in m.group(2).split(",") if d) \
+                >= stacked:
+            ops.append((m.group(3), line.split("=")[0].strip()))
+    kinds = {op for op, _ in ops}
+    assert "scatter" in kinds
+    offending = [(op, name) for op, name in ops
+                 if op in ("copy", "dynamic-update-slice")
+                 or "dynamic-update-slice" in name]
+    assert not offending, offending
