@@ -312,7 +312,7 @@ TUNED_KERNEL_KNOBS = {
     "rmsnorm": ("br",),
     "rmsnorm_pallas": ("br",),
     "ssd": ("chunk",),
-    "ssd_chunk_pallas": ("chunk",),
+    "ssd_scan": ("chunk",),
 }
 
 

@@ -930,9 +930,16 @@ def ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 128, init_state=None):
     Intra-chunk term is attention-like (quadratic in chunk only); inter-chunk
     states pass through a short scan over chunks.  Matches ssd_reference to
     fp32 tolerance (tested).  Returns (y, final_state).
+
+    Which body runs is decided when the program is lowered: on a TPU, the
+    fused Pallas kernels of ``repro.kernels.ssd_scan`` (forward and
+    backward), where one group of B and C, a chunk that is a multiple of
+    8, head sizes the kernels tile, a state that fits their VMEM
+    (``plan``) and a mesh of one device allow them;
+    elsewhere, and on every other platform, the XLA body
+    (``_ssd_chunked_xla``), which is the kernels' oracle in the tests.
     """
     b, l, h, p = x.shape
-    n = B.shape[-1]
     Q = min(chunk, l)
     if l % Q:
         # pad tail with dt=0 tokens: zero decay-rate and zero input, so the
@@ -945,6 +952,33 @@ def ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 128, init_state=None):
         y, hfin = ssd_chunked(x, dt, A, B, C, D, chunk=chunk,
                               init_state=init_state)
         return y[:, :l], hfin
+    h0 = (jnp.zeros((b, h, p, B.shape[-1]), jnp.float32)
+          if init_state is None else init_state.astype(jnp.float32))
+    xla = functools.partial(_ssd_chunked_xla, chunk=Q)
+    # imported here: the kernel package imports this module (its oracle)
+    from repro.kernels.ssd_scan.kernel import plan, ssd_scan
+    if (B.shape[2] != 1 or not _on_one_device()
+            or plan(h, p, B.shape[-1], Q) is None):
+        return xla(x, dt, A, B, C, D, h0)
+    return lax.platform_dependent(
+        x, dt, A, B, C, D, h0, default=xla,
+        tpu=functools.partial(ssd_scan, chunk=Q))
+
+
+def _on_one_device() -> bool:
+    """Whether the program being traced runs on one device: no mesh
+    bound, or a mesh of one (a kernel is not split over a mesh)."""
+    rules = active_rules()
+    mesh = rules.get("mesh") if rules else None
+    return mesh is None or mesh.size == 1
+
+
+def _ssd_chunked_xla(x, dt, A, B, C, D, h0, *, chunk: int):
+    """``ssd_chunked``'s XLA body: the length a multiple of ``chunk``,
+    the initial state ``h0`` float32."""
+    b, l, h, p = x.shape
+    n = B.shape[-1]
+    Q = chunk
     nc = l // Q
     xf = x.astype(jnp.float32).reshape(b, nc, Q, h, p)
     dtf = dt.astype(jnp.float32).reshape(b, nc, Q, h)
@@ -971,9 +1005,6 @@ def ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 128, init_state=None):
     edecay = jnp.exp(a_tot[:, :, None, :] - a_cs)    # [b,nc,Q,h]
     states = jnp.einsum("bckh,bckh,bckhp,bckn->bchpn",
                         edecay, dtf, xf, Bf)
-
-    h0 = (jnp.zeros((b, h, p, n), jnp.float32) if init_state is None
-          else init_state.astype(jnp.float32))
 
     def carry(hprev, inp):
         s_c, atot_c = inp                            # [b,h,p,n], [b,h]

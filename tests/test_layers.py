@@ -148,6 +148,72 @@ def test_ssd_chunked_forward_matches_recurrence_at_published_dt(chunk):
                                rtol=0)
 
 
+@pytest.fixture
+def ssd_kernel_path(monkeypatch):
+    """``ssd_chunked`` as lowered for a TPU, its kernels in interpret mode:
+    the platform choice takes the ``tpu`` branch, whose kernel runs
+    interpreted on the CPU."""
+    from repro.kernels.ssd_scan import kernel
+
+    scan = kernel.ssd_scan
+    monkeypatch.setattr(kernel, "ssd_scan",
+                        lambda *a, chunk: scan(*a, chunk, True))
+    monkeypatch.setattr(L.lax, "platform_dependent",
+                        lambda *a, default, tpu: tpu(*a))
+
+
+@pytest.mark.parametrize("chunk,n,cut", [(8, 16, 3), (16, 128, 5),
+                                         (32, 16, 0), (32, 128, 11)])
+@pytest.mark.parametrize("with_init", [False, True])
+def test_ssd_chunked_kernel_path_matches_xla_body(ssd_kernel_path, chunk, n,
+                                                  cut, with_init):
+    """The kernel path of ``ssd_chunked`` (dt as published; ``cut``
+    positions short of two chunks, so that the padding runs) against its
+    XLA body and the recurrence: y, the final state, and the gradients of
+    x, dt, A, B, C, D and ``init_state``."""
+    x, dt, A, Bm, Cm, D = _ssd_inputs(chunk, "published", b=2, n=n)
+    l = x.shape[1] - cut
+    x, dt, Bm, Cm = x[:, :l], dt[:, :l], Bm[:, :l], Cm[:, :l]
+    h0 = (jax.random.normal(jax.random.PRNGKey(9), x.shape[:1] + x.shape[2:]
+                            + (n,)) * 0.5 if with_init else None)
+    dy = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+
+    def run(x, dt, A, Bm, Cm, D, h0):
+        (y, s), back = jax.vjp(
+            lambda *a: L.ssd_chunked(*a[:6], chunk=chunk, init_state=a[6]),
+            x, dt, A, Bm, Cm, D, h0)
+        return (y, s) + back((dy, jnp.ones_like(s)))
+
+    args = (x, dt, A, Bm, Cm, D, h0)
+    assert "ssd_bwd" in str(jax.make_jaxpr(run)(*args))
+    kernel = jax.jit(run)(*args)
+    with pytest.MonkeyPatch.context() as undo:
+        undo.setattr(L, "_on_one_device", lambda: False)
+        body = jax.jit(run)(*args)
+    names = ("y", "state", "dx", "ddt", "dA", "dB", "dC", "dD", "dh0")
+    for name, got, want in zip(names, kernel, body):
+        if want is None:
+            continue
+        scale = max(1.0, float(np.max(np.abs(np.asarray(want)))))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5 * scale, rtol=1e-5,
+                                   err_msg=name)
+    yr, sr = L.ssd_reference(x, dt, A, Bm, Cm, D, init_state=h0)
+    np.testing.assert_allclose(np.asarray(kernel[0]), np.asarray(yr),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(kernel[1]), np.asarray(sr),
+                               atol=1e-5)
+
+
+def test_ssd_chunked_runs_the_xla_body_off_the_tpu():
+    """Lowered for the CPU, ``ssd_chunked`` holds no Pallas kernel, even
+    where the kernel's conditions hold."""
+    x, dt, A, Bm, Cm, D = _ssd_inputs(16, "published")
+    text = jax.jit(lambda *a: L.ssd_chunked(*a, chunk=16)).lower(
+        x, dt, A, Bm, Cm, D).as_text()
+    assert "ssd_fwd" not in text and "tpu_custom_call" not in text
+
+
 def test_mamba2_init_follows_the_published_module():
     """A = exp(A_log) uniform in [1, 16]; softplus(dt_bias) log-uniform in
     [0.001, 0.1]; D one; a bias on the conv over x, B and C within

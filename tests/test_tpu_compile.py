@@ -14,6 +14,7 @@ import functools
 import math
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -28,8 +29,8 @@ from repro.kernels.matmul.kernel import matmul_pallas
 from repro.kernels.rmsnorm import ops as rmsnorm_ops
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 from repro.kernels.ssd_scan import ops as ssd_ops
-from repro.kernels.ssd_scan.kernel import ssd_chunk_pallas
-from repro.models import build, get_config, transformer
+from repro.kernels.ssd_scan.kernel import ssd_forward, ssd_scan
+from repro.models import build, get_config, layers, transformer
 
 
 @pytest.fixture(scope="module")
@@ -97,18 +98,85 @@ def test_rmsnorm_block_over_the_vmem_limit_fails_validation(one_chip):
         _compile_text(functools.partial(rmsnorm_pallas, br=2048), x, s)
 
 
+def _ssd_args(sds, b, l, h, p, n, dtype):
+    """x, dt, A, B, C, D and an initial state as shapes."""
+    return (sds((b, l, h, p), dtype), sds((b, l, h), jnp.float32),
+            sds((h,), jnp.float32), sds((b, l, 1, n), dtype),
+            sds((b, l, 1, n), dtype), sds((h,), jnp.float32),
+            sds((b, h, p, n), jnp.float32))
+
+
 def test_ssd_scan_compiles_at_mamba2_780m_widths(one_chip):
-    # mamba2-780m: 48 heads of 64, state 128, one 512-token sequence
-    b, l, h, p, n = 1, 512, 48, 64, 128
-    x = jax.ShapeDtypeStruct((b, l, h, p), jnp.bfloat16, sharding=one_chip)
-    dt = jax.ShapeDtypeStruct((b, l, h), jnp.float32, sharding=one_chip)
-    A = jax.ShapeDtypeStruct((h,), jnp.float32, sharding=one_chip)
-    B = jax.ShapeDtypeStruct((b, l, 1, n), jnp.bfloat16, sharding=one_chip)
-    Bf = jax.ShapeDtypeStruct((b, l, n), jnp.bfloat16, sharding=one_chip)
-    eff = ssd_ops.blocks(x, B)
-    text = _compile_text(functools.partial(ssd_chunk_pallas, **eff),
-                         x, dt, A, Bf, Bf)
+    # mamba2-780m: 48 heads of 64, state 128, one 512-token sequence; the
+    # forward kernel with its residual and the backward kernel
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = _ssd_args(sds, 1, 512, 48, 64, 128, jnp.bfloat16)
+    chunk = ssd_ops.blocks(args[0], args[3])["chunk"]
+    text = _compile_text(functools.partial(ssd_forward, chunk=chunk,
+                                           save=True), *args)
     assert "tpu_custom_call" in text
+
+    def loss(*a):
+        y, s = ssd_scan(*a, chunk)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(s)
+    text = _compile_text(jax.grad(loss, argnums=tuple(range(7))), *args)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def _bench_scope_of():
+    """``bench/ssm_scopes.scope_of``: the map the benchmark's ``ssd`` and
+    ``mixer`` metrics read a compiled step through."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.ssm_scopes import scope_of
+    return scope_of
+
+
+def test_mamba2_block_gradient_runs_the_ssd_kernels(one_chip):
+    """jax.grad through ``mamba2_block`` under ``jax.checkpoint`` at
+    mamba2-780m's widths and the train cell's 2 x 8,192 positions, lowered
+    for one v5e: the SSD scan's forward and backward Mosaic kernels are
+    both in the program, each under the ``ssd`` scope that
+    ``ssd_ms.train8k`` reads (a backward without it would leave the
+    scan's time out of the metric), and the scan's compiled temporaries
+    are below those of the XLA body at the same shapes."""
+    scope_of = _bench_scope_of()
+    cfg = get_config("mamba2-780m")
+    b, l = 2, 8192
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: layers.init_mamba2(jax.random.PRNGKey(0), cfg)))
+    block = jax.checkpoint(lambda p, x: layers.mamba2_block(p, x, cfg))
+    text = _compile_text(jax.grad(
+        lambda p, x: jnp.sum(block(p, x).astype(jnp.float32)),
+        argnums=(0, 1)), params, sds((b, l, cfg.d_model), jnp.bfloat16))
+    kernels = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT\s+)?%([\w-]+)", line).group(1)
+            kernels[name] = scope_of(re.search(r'op_name="([^"]*)"',
+                                               line).group(1))
+    assert sorted(kernels) == ["ssd_bwd", "ssd_fwd"], kernels
+    assert set(kernels.values()) == {"ssd"}, kernels
+
+    args = _ssd_args(sds, b, l, cfg.ssm_heads, cfg.ssm_head_dim,
+                     cfg.ssm_state, jnp.bfloat16)
+
+    def temp_bytes(scan):
+        def loss(*a):
+            y, _ = jax.checkpoint(scan)(*a)
+            return jnp.sum(y.astype(jnp.float32))
+        return jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+            *args).compile().memory_analysis().temp_size_in_bytes
+    chunk = cfg.ssm_chunk
+    kernel = temp_bytes(lambda *a: layers.ssd_chunked(
+        *a[:6], chunk=chunk, init_state=a[6]))
+    xla = temp_bytes(functools.partial(layers._ssd_chunked_xla, chunk=chunk))
+    assert kernel < xla, (kernel, xla)
 
 
 def test_histogram_compiles(one_chip):
@@ -125,9 +193,9 @@ def _flash_gqa(f32):
 
 def _ssd_small(f32):
     x, B = f32((2, 512, 4, 64)), f32((2, 512, 1, 64))
-    return (functools.partial(ssd_chunk_pallas, **ssd_ops.blocks(x, B)),
-            x, f32((2, 512, 4)), f32((4,)), f32((2, 512, 64)),
-            f32((2, 512, 64)))
+    return (functools.partial(ssd_forward, **ssd_ops.blocks(x, B)),
+            x, f32((2, 512, 4)), f32((4,)), B, B, f32((4,)),
+            f32((2, 4, 64, 64)))
 
 
 # The shapes the builtin scopes run the kernels at (`repro run` on a
