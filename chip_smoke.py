@@ -148,8 +148,6 @@ def cached_logits_error(engine, req) -> float:
     then ragged decode steps over the KV cache, feeding the tokens the
     request generated.  The reference is one forward pass over the same
     tokens."""
-    from repro.models import transformer
-
     api, params, scfg = engine.api, engine.params, engine.cfg
     prompt, out = list(req.prompt), list(req.output)
     n = len(prompt)
@@ -161,8 +159,7 @@ def cached_logits_error(engine, req) -> float:
                                          logit_pos=n - 1)
     cache = dict(cache, pos=jnp.asarray([n], jnp.int32))
     cached = [logits[0, -1]]
-    decode = jax.jit(
-        lambda p, t, c: transformer.decode_step_ragged(api.cfg, p, t, c))
+    decode = jax.jit(api.decode_step)
     for tok in out[:-1]:
         logits, cache = decode(params, jnp.asarray([[tok]], jnp.int32),
                                cache)

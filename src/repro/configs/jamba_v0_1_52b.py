@@ -4,8 +4,8 @@
 top-2 on every other layer.  Period-8 superblocks: attention at block
 index 4, Mamba elsewhere (1:7); no positional encoding (use_rope=False).
 Jamba v0.1 uses Mamba-1 layers; we implement the Mamba-2/SSD block (same
-state budget: ssm_state=16, d_inner=2*d, conv4) — deviation recorded in
-DESIGN.md.  Sub-quadratic: runs the long_500k shape.
+state budget: ssm_state=16, d_inner=2*d, conv4), a deviation.
+Sub-quadratic: runs the long_500k shape.
 """
 from repro.models.config import ModelConfig, register_arch
 

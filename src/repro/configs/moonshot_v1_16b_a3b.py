@@ -3,8 +3,8 @@
 48L d_model=2048 16H (GQA kv=16) vocab=163840; MoE: 64 routed experts,
 top-6, per-expert d_ff=1408 (fine-grained).  The brief lists exactly these
 figures; every layer is MoE (no shared experts are listed, so none are
-instantiated — deviation from upstream Moonlight's 2 shared experts is
-noted in DESIGN.md).
+instantiated — a deviation from upstream Moonlight's 2 shared
+experts).
 """
 from repro.models.config import ModelConfig, register_arch
 

@@ -1,8 +1,7 @@
 """stablelm-12b [hf:stabilityai/stablelm-2-12b; hf].
 
 40L d_model=5120 32H (GQA kv=8) d_ff=13824 vocab=100352; head_dim 160
-(d_model/H; not MXU-128-aligned — a deliberate roofline stressor, see
-EXPERIMENTS.md §Roofline).
+(d_model/H; not MXU-128-aligned — a deliberate roofline stressor).
 """
 from repro.models.config import ModelConfig, register_arch
 

@@ -4,8 +4,7 @@
 12 decoder layers; LayerNorm + GELU, learned decoder positions, sinusoidal
 encoder positions.  The mel/conv frontend is a STUB: input_specs supplies
 precomputed frame embeddings [B, 1500, d].  Attention biases of the
-upstream checkpoint are omitted (systems-level reproduction; noted in
-DESIGN.md).
+upstream checkpoint are omitted (systems-level reproduction).
 """
 from repro.models.config import ModelConfig, register_arch
 

@@ -14,7 +14,7 @@ For every (architecture × input-shape × mesh) cell:
   3. ``jax.jit(step, in_shardings, out_shardings).lower(...).compile()``;
   4. print ``memory_analysis()`` (proves it fits) and ``cost_analysis()``
      (FLOPs/bytes for §Roofline), parse collective bytes from the HLO;
-  5. append the cell's record to a results JSON for EXPERIMENTS.md.
+  5. append the cell's record to a results JSON.
 
 Usage:
   python -m repro.launch.dryrun --arch llama3.2-1b --shape train_4k

@@ -70,13 +70,12 @@ class ModelConfig:
 
     # --- numerics / implementation knobs (perf levers, not architecture) ---
     dtype: str = "bfloat16"
-    attn_impl: str = "flash_xla"    # flash_xla | naive | flash_pallas
+    attn_impl: str = "flash_xla"    # flash_xla | naive
     attn_chunk_q: int = 512
     attn_chunk_k: int = 512
     causal_skip: bool = True        # skip fully-masked k-chunks (triangular sched)
     loss_chunk: int = 0             # 0 = unchunked cross-entropy
     remat: str = "none"             # none | full | dots
-    scan_layers: bool = True
     logits_dtype: str = "float32"
 
     # ------------------------------------------------------------------

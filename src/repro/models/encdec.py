@@ -91,15 +91,10 @@ def encode(cfg: ModelConfig, params: Params, frames: jax.Array) -> jax.Array:
     B, Se, d = frames.shape
     x = frames.astype(jnp.dtype(cfg.dtype)) + \
         sinusoids(Se, d).astype(jnp.dtype(cfg.dtype))[None]
-    ck = L.pick_chunk(Se, cfg.attn_chunk_k)
 
     def block(x, p):
         h = norm_f(p["ln1"], x, cfg.norm_eps)
-        q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
-                         cfg.hd, False, cfg.norm_eps)
-        o = L.flash_attention_xla(q, k, v, causal=False,
-                                  chunk_q=ck, chunk_k=ck)
-        x = x + o.reshape(B, Se, -1) @ p["attn"]["wo"].astype(x.dtype)
+        x = x + L.attention(p["attn"], h, None, cfg, causal=False)[0]
         h = norm_f(p["ln2"], x, cfg.norm_eps)
         return x + L.mlp(p["mlp"], h, cfg.act), None
 
@@ -134,13 +129,8 @@ def _decoder(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
     def block(x, p):
         h = norm_f(p["ln1"], x, cfg.norm_eps)
-        q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
-                         cfg.hd, False, cfg.norm_eps)
-        o = L.flash_attention_xla(q, k, v, causal=True,
-                                  chunk_q=cfg.attn_chunk_q,
-                                  chunk_k=cfg.attn_chunk_k,
-                                  causal_skip=cfg.causal_skip)
-        x = x + o.reshape(B, S, -1) @ p["attn"]["wo"].astype(x.dtype)
+        o, (k, v) = L.attention(p["attn"], h, None, cfg)
+        x = x + o
         # cross-attention
         h = norm_f(p["ln_x"], x, cfg.norm_eps)
         qx = (h @ p["cross"]["wq"].astype(x.dtype)).reshape(
@@ -207,10 +197,8 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, Any],
     k, v, xk, xv = kv
     S = batch["tokens"].shape[1]
     cache = dict(cache)
-    cache["k"] = lax.dynamic_update_slice_in_dim(
-        cache["k"], k.astype(cache["k"].dtype), 0, axis=2)
-    cache["v"] = lax.dynamic_update_slice_in_dim(
-        cache["v"], v.astype(cache["v"].dtype), 0, axis=2)
+    cache["k"] = L.write_kv(cache["k"], k, 0)
+    cache["v"] = L.write_kv(cache["v"], v, 0)
     cache["xk"] = xk.astype(cache["xk"].dtype)
     cache["xv"] = xv.astype(cache["xv"].dtype)
     cache["pos"] = jnp.asarray(S, jnp.int32)
@@ -228,17 +216,12 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
     x = x + lax.dynamic_slice_in_dim(params["pos_embed"], pos, 1,
                                      axis=0).astype(x.dtype)[None, 0]
 
-    def block(x, inp):
-        p, k_c, v_c, xk, xv = inp
+    def block(carry, inp):
+        x, kv = carry
+        p, i, xk, xv = inp
         h = norm_f(p["ln1"], x, cfg.norm_eps)
-        q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
-                         cfg.hd, False, cfg.norm_eps)
-        k_c = lax.dynamic_update_slice_in_dim(
-            k_c, k.astype(k_c.dtype), pos, axis=1)
-        v_c = lax.dynamic_update_slice_in_dim(
-            v_c, v.astype(v_c.dtype), pos, axis=1)
-        o = L.decode_attention(q, k_c, v_c, pos + 1)
-        x = x + o.reshape(B, 1, -1) @ p["attn"]["wo"].astype(x.dtype)
+        o, kv = L.attention_decode(p["attn"], h, kv, i, pos, None, None, cfg)
+        x = x + o
         h = norm_f(p["ln_x"], x, cfg.norm_eps)
         qx = (h @ p["cross"]["wq"].astype(x.dtype)).reshape(
             B, 1, cfg.num_heads, cfg.hd)
@@ -246,11 +229,12 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
         x = x + ox.reshape(B, 1, -1) @ p["cross"]["wo"].astype(x.dtype)
         h = norm_f(p["ln2"], x, cfg.norm_eps)
         x = x + L.mlp(p["mlp"], h, cfg.act)
-        return x, (k_c, v_c)
+        return (x, kv), None
 
-    x, (k_new, v_new) = lax.scan(
-        block, x, (params["dec_blocks"], cache["k"], cache["v"],
-                   cache["xk"], cache["xv"]))
+    (x, (k_new, v_new)), _ = lax.scan(
+        block, (x, (cache["k"], cache["v"])),
+        (params["dec_blocks"], jnp.arange(cfg.num_layers), cache["xk"],
+         cache["xv"]))
     x = norm_f(params["final_norm"], x, cfg.norm_eps)
     out = L.unembed(unembed_table(params), x, jnp.dtype(cfg.logits_dtype))
     cache = dict(cache, k=k_new, v=v_new, pos=pos + 1)

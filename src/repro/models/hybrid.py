@@ -116,23 +116,8 @@ def _superblock(cfg: ModelConfig, p: Params, x: jax.Array,
             else:
                 y = L.mamba2_block(pm, h, cfg)
         else:
-            pa = at(p["attn"], i_attn)
+            y, kv = L.attention(at(p["attn"], i_attn), h, positions, cfg)
             i_attn += 1
-            q, k, v = L._qkv(pa, h, cfg.num_heads, cfg.num_kv_heads, cfg.hd,
-                             cfg.qk_norm, cfg.norm_eps)
-            q = L.apply_rope(q, positions, cfg.rope_theta,
-                             cfg.mrope_sections, cfg.use_rope)
-            k = L.apply_rope(k, positions, cfg.rope_theta,
-                             cfg.mrope_sections, cfg.use_rope)
-            o = L.flash_attention_xla(q, k, v, causal=True,
-                                      chunk_q=cfg.attn_chunk_q,
-                                      chunk_k=cfg.attn_chunk_k,
-                                      causal_skip=cfg.causal_skip)
-            B, S = x.shape[:2]
-            y = o.reshape(B, S, cfg.num_heads * cfg.hd) @ \
-                pa["wo"].astype(x.dtype)
-            if collect:
-                kv = (k, v)
         x = x + y
         h = L.rms_norm({"scale": p["ln2"][j]}, x, cfg.norm_eps)
         if is_moe:
@@ -212,13 +197,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, Any],
             cache: Dict[str, Any]):
     h, _aux, caches = hidden(cfg, params, batch, collect=True)
-    k, v = caches["kv"]                              # [nb,B,S,K,hd]
+    k, v = caches["kv"]
     S = batch["tokens"].shape[1]
     out_cache = dict(cache)
-    out_cache["k"] = lax.dynamic_update_slice_in_dim(
-        cache["k"], k.astype(cache["k"].dtype), 0, axis=2)
-    out_cache["v"] = lax.dynamic_update_slice_in_dim(
-        cache["v"], v.astype(cache["v"].dtype), 0, axis=2)
+    out_cache["k"] = L.write_kv(cache["k"], k, 0)
+    out_cache["v"] = L.write_kv(cache["v"], v, 0)
     out_cache["state"] = caches["state"].astype(cache["state"].dtype)
     out_cache["conv"] = jax.tree_util.tree_map(
         lambda t, c: t.astype(c.dtype), caches["conv"], cache["conv"])
@@ -233,12 +216,13 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
     B = tokens.shape[0]
     pos = cache["pos"]
     x = L.embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
-    positions = jnp.broadcast_to(pos[None, None], (B, 1))
+    positions = L.decode_positions(pos, B, cfg.mrope_sections)
     pat = _pattern(cfg)
     at = lambda tree, i: jax.tree_util.tree_map(lambda a: a[i], tree)
 
-    def block(x, inp):
-        p, k_c, v_c, st, cv = inp
+    def block(carry, inp):
+        x, kv = carry
+        p, sb, st, cv = inp
         i_ssm = i_attn = i_dense = i_moe = 0
         st_new, cv_new = [], []
         for j, (mixer, is_moe) in enumerate(pat):
@@ -253,21 +237,10 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
                     lambda a, b: a.astype(b.dtype), t_n, tail_i))
                 i_ssm += 1
             else:
-                pa = at(p["attn"], i_attn)
+                # one attention layer a period: the cache's layer sb
+                y, kv = L.attention_decode(at(p["attn"], i_attn), h, kv, sb,
+                                           pos, None, positions, cfg)
                 i_attn += 1
-                q, k, v = L._qkv(pa, h, cfg.num_heads, cfg.num_kv_heads,
-                                 cfg.hd, cfg.qk_norm, cfg.norm_eps)
-                q = L.apply_rope(q, positions, cfg.rope_theta,
-                                 cfg.mrope_sections, cfg.use_rope)
-                k = L.apply_rope(k, positions, cfg.rope_theta,
-                                 cfg.mrope_sections, cfg.use_rope)
-                k_c = lax.dynamic_update_slice_in_dim(
-                    k_c, k.astype(k_c.dtype), pos, axis=1)
-                v_c = lax.dynamic_update_slice_in_dim(
-                    v_c, v.astype(v_c.dtype), pos, axis=1)
-                o = L.decode_attention(q, k_c, v_c, pos + 1)
-                y = o.reshape(B, 1, cfg.num_heads * cfg.hd) @ \
-                    pa["wo"].astype(x.dtype)
             x = x + y
             h = L.rms_norm({"scale": p["ln2"][j]}, x, cfg.norm_eps)
             if is_moe:
@@ -277,12 +250,14 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
                 m = L.mlp(at(p["mlp"], i_dense), h, cfg.act)
                 i_dense += 1
             x = x + m
-        return x, (k_c, v_c, jnp.stack(st_new),
-                   jax.tree_util.tree_map(lambda *a: jnp.stack(a), *cv_new))
+        return (x, kv), (jnp.stack(st_new),
+                         jax.tree_util.tree_map(lambda *a: jnp.stack(a),
+                                                *cv_new))
 
-    x, (k_new, v_new, st_new, cv_new) = lax.scan(
-        block, x, (params["blocks"], cache["k"], cache["v"],
-                   cache["state"], cache["conv"]))
+    nb = cfg.num_layers // cfg.attn_every
+    (x, (k_new, v_new)), (st_new, cv_new) = lax.scan(
+        block, (x, (cache["k"], cache["v"])),
+        (params["blocks"], jnp.arange(nb), cache["state"], cache["conv"]))
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     out = L.unembed(unembed_table(params), x, jnp.dtype(cfg.logits_dtype))
     return out, {"k": k_new, "v": v_new, "state": st_new, "conv": cv_new,

@@ -241,7 +241,7 @@ def _split_pairs(Sq, Sk, cq, ck, causal, causal_skip):
 def _flash_fwd_scan(q, kr, vr, causal, cq, ck, causal_skip):
     """Online-softmax over chunk pairs.  q [B,Sq,H,D]; kr/vr [B,Sk,H,D].
 
-    Flash-v2-style schedule (beyond-paper lever, see EXPERIMENTS.md §Perf):
+    Flash-v2-style schedule (beyond-paper lever):
       * causal pairs split into OFF-DIAGONAL (no mask, no -inf selects —
         ~(nq-1)/nq of all pairs) and DIAGONAL scans (masked);
       * dots consume the INPUT dtype with fp32 accumulation
@@ -436,7 +436,7 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array,
     * backward recomputes per chunk-pair (flash algorithm), so residuals
       are O(S·H·D) — a lax.scan with autodiff would instead save every
       per-pair carry (observed 16 GiB/device on llama train_4k before this
-      custom VJP; see EXPERIMENTS.md §Perf);
+      custom VJP);
     * ``causal_skip`` schedules only lower-triangular chunk pairs.
 
     The TPU fast path is the Pallas kernel in repro.kernels.flash_attention;
@@ -518,12 +518,19 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         return o.reshape(B, 1, H, D).astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# the attention sublayer and the KV cache
+# ---------------------------------------------------------------------------
+
 @jax.named_scope("attention")
-def attention_block(p: Params, x: jax.Array, positions: jax.Array, *,
-                    cfg, causal: bool = True) -> jax.Array:
-    """Full self-attention sublayer (projections + rope + attention)."""
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    q, k, v = _qkv(p, x, H, K, hd, cfg.qk_norm, cfg.norm_eps)
+def attention(p: Params, x: jax.Array, positions: Optional[jax.Array], cfg,
+              causal: bool = True):
+    """Self-attention over whole sequences (train, prefill): projections,
+    rope, the kernel ``cfg.attn_impl`` names, ``wo``.  x [B,S,d] →
+    (out [B,S,d], (k, v)), the rotated keys and values a prefill caches."""
+    B, S = x.shape[:2]
+    H, hd = cfg.num_heads, cfg.hd
+    q, k, v = _qkv(p, x, H, cfg.num_kv_heads, hd, cfg.qk_norm, cfg.norm_eps)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections,
                    cfg.use_rope)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections,
@@ -532,11 +539,70 @@ def attention_block(p: Params, x: jax.Array, positions: jax.Array, *,
         o = naive_attention(q, k, v, causal=causal)
     else:
         o = flash_attention_xla(q, k, v, causal=causal,
-                                chunk_q=cfg.attn_chunk_q,
-                                chunk_k=cfg.attn_chunk_k,
+                                chunk_q=pick_chunk(S, cfg.attn_chunk_q),
+                                chunk_k=pick_chunk(S, cfg.attn_chunk_k),
                                 causal_skip=cfg.causal_skip)
-    B, S = x.shape[:2]
-    return o.reshape(B, S, H * hd) @ p["wo"].astype(x.dtype)
+    return o.reshape(B, S, H * hd) @ p["wo"].astype(x.dtype), (k, v)
+
+
+def decode_positions(pos: jax.Array, B: int,
+                     mrope_sections: Tuple[int, ...] = ()) -> jax.Array:
+    """Rope positions of the one token each of B rows decodes: ``pos`` is
+    a scalar (every row at one position) or ``[B]`` (each row at its own)."""
+    positions = (pos[:, None] if pos.ndim
+                 else jnp.broadcast_to(pos[None, None], (B, 1)))
+    if mrope_sections:
+        positions = jnp.broadcast_to(positions[None], (3, B, 1))
+    return positions
+
+
+def kv_rows(pos: jax.Array, B: int) -> Optional[jax.Array]:
+    """The rows a ``[B]`` ``pos`` writes one by one (``write_kv``); None for
+    a scalar ``pos``, which writes them all at once.  Made once a step,
+    before the layer scan, as the rope positions are."""
+    return jnp.arange(B) if pos.ndim else None
+
+
+def write_kv(cache: jax.Array, new: jax.Array, pos, layer=None,
+             rows: Optional[jax.Array] = None) -> jax.Array:
+    """Write keys or values into a stacked cache ``[L,B,S,K,hd]``, the one
+    function that knows that layout.
+
+    With ``layer`` None, ``new`` is every layer's ``[L,B,s,K,hd]`` from
+    position ``pos`` on (prefill).  Otherwise it is one token a row of
+    layer ``layer``, ``[B,1,K,hd]``: a scalar ``pos`` writes every row
+    there with one ``dynamic_update_slice`` (no scatter, so a
+    sequence-sharded cache stays sharded); a ``[B]`` ``pos`` writes row
+    ``rows[b]`` at ``pos[b]`` (``kv_rows``), a scatter."""
+    if layer is None:
+        return lax.dynamic_update_slice_in_dim(
+            cache, new.astype(cache.dtype), pos, axis=2)
+    if rows is None:
+        return lax.dynamic_update_slice(
+            cache, new[None].astype(cache.dtype), (layer, 0, pos, 0, 0))
+    return cache.at[layer, rows, pos].set(new[:, 0].astype(cache.dtype))
+
+
+@jax.named_scope("attention")
+def attention_decode(p: Params, x: jax.Array, kv, layer, pos: jax.Array,
+                     rows: Optional[jax.Array],
+                     positions: Optional[jax.Array], cfg):
+    """Self-attention of one new token a row (decode): projections, rope at
+    ``positions`` (``decode_positions``), the write of its keys and values
+    at ``pos`` into layer ``layer`` of the stacked caches ``kv = (k, v)``,
+    attention over the layer's first ``pos + 1`` positions, ``wo``.
+    x [B,1,d] → (out [B,1,d], kv)."""
+    B = x.shape[0]
+    H, hd = cfg.num_heads, cfg.hd
+    q, k, v = _qkv(p, x, H, cfg.num_kv_heads, hd, cfg.qk_norm, cfg.norm_eps)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections,
+                   cfg.use_rope)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections,
+                   cfg.use_rope)
+    k_all = write_kv(kv[0], k, pos, layer, rows)
+    v_all = write_kv(kv[1], v, pos, layer, rows)
+    o = decode_attention(q, k_all[layer], v_all[layer], pos + 1)
+    return o.reshape(B, 1, H * hd) @ p["wo"].astype(x.dtype), (k_all, v_all)
 
 
 # ---------------------------------------------------------------------------

@@ -65,39 +65,21 @@ def unembed_table(params: Params) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# forward (train / prefill)
+# the block; forward (train / prefill)
 # ---------------------------------------------------------------------------
 
-def _block_apply(cfg: ModelConfig, p: Params, x: jax.Array,
-                 positions: jax.Array, collect_kv: bool):
-    """One transformer block.  Returns (x, aux, (k, v) | None)."""
-    h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
-    with jax.named_scope("attention"):
-        q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
-                         cfg.hd, cfg.qk_norm, cfg.norm_eps)
-        q = L.apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections,
-                         cfg.use_rope)
-        k = L.apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections,
-                         cfg.use_rope)
-        if cfg.attn_impl == "naive":
-            o = L.naive_attention(q, k, v, causal=True)
-        else:
-            o = L.flash_attention_xla(q, k, v, causal=True,
-                                      chunk_q=cfg.attn_chunk_q,
-                                      chunk_k=cfg.attn_chunk_k,
-                                      causal_skip=cfg.causal_skip)
-        B, S = x.shape[:2]
-        o = o.reshape(B, S, cfg.num_heads * cfg.hd) @ \
-            p["attn"]["wo"].astype(x.dtype)
+def _block(cfg: ModelConfig, p: Params, x: jax.Array, attend):
+    """One transformer block: norm, attention, residual, norm, MLP or MoE,
+    residual.  ``attend(p_attn, h)`` is the attention sublayer, returning
+    (out, kv).  Returns (x, aux, kv)."""
+    o, kv = attend(p["attn"], L.rms_norm(p["ln1"], x, cfg.norm_eps))
     x = x + o
-
     h = L.rms_norm(p["ln2"], x, cfg.norm_eps)
     if "moe" in p:
         m, aux = L.moe_layer(p["moe"], h, cfg)
     else:
         m, aux = L.mlp(p["mlp"], h, cfg.act), jnp.zeros((), jnp.float32)
-    x = x + m
-    return x, aux, ((k, v) if collect_kv else None)
+    return x + m, aux, kv
 
 
 def _maybe_remat(fn, cfg: ModelConfig):
@@ -136,24 +118,12 @@ def hidden(cfg: ModelConfig, params: Params, batch: Dict[str, Any],
     x, positions = _embed_inputs(cfg, params, batch)
 
     def block(x, p):
-        x, aux, kv = _block_apply(cfg, p, x, positions, collect_kv)
-        return x, (aux, kv)
+        x, aux, kv = _block(cfg, p, x, lambda pa, h: L.attention(
+            pa, h, positions, cfg))
+        return x, (aux, kv if collect_kv else None)
 
-    block = _maybe_remat(block, cfg)
-    if cfg.scan_layers:
-        x, (aux, kv) = lax.scan(block, x, params["blocks"])
-        aux = jnp.sum(aux)
-    else:
-        auxs, ks, vs = [], [], []
-        for i in range(cfg.num_layers):
-            p_i = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
-            x, (a, kv_i) = block(x, p_i)
-            auxs.append(a)
-            if collect_kv:
-                ks.append(kv_i[0])
-                vs.append(kv_i[1])
-        aux = jnp.sum(jnp.stack(auxs))
-        kv = (jnp.stack(ks), jnp.stack(vs)) if collect_kv else None
+    x, (aux, kv) = lax.scan(_maybe_remat(block, cfg), x, params["blocks"])
+    aux = jnp.sum(aux)
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     return x, aux, kv
 
@@ -197,15 +167,11 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, Any],
     ``logit_pos``: position whose logits to return (traced scalar ok) —
     the serve engine passes len(prompt)-1 for right-padded prompts.
     """
-    h, _aux, kv = hidden(cfg, params, batch, collect_kv=True)
-    k, v = kv                                       # [L,B,S,K,hd]
-    S = k.shape[2]
+    h, _aux, (k, v) = hidden(cfg, params, batch, collect_kv=True)
     cache = dict(cache)
-    cache["k"] = lax.dynamic_update_slice_in_dim(
-        cache["k"], k.astype(cache["k"].dtype), 0, axis=2)
-    cache["v"] = lax.dynamic_update_slice_in_dim(
-        cache["v"], v.astype(cache["v"].dtype), 0, axis=2)
-    cache["pos"] = jnp.asarray(S, jnp.int32)
+    cache["k"] = L.write_kv(cache["k"], k, 0)
+    cache["v"] = L.write_kv(cache["v"], v, 0)
+    cache["pos"] = jnp.asarray(batch["tokens"].shape[1], jnp.int32)
     if logit_pos is None:
         h_last = h[:, -1:]
     else:
@@ -215,100 +181,49 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, Any],
     return out, cache
 
 
+def write_slot(pool_cache: Dict[str, Any], row_cache: Dict[str, Any],
+               slot):
+    """Write a one-row cache (a prefill's, its ``pos`` ``[1]``) into slot
+    ``slot`` of a pool cache whose ``pos`` is ``[B]``.  ``slot`` is an int
+    or a traced scalar: one compiled program serves every slot.  A one-slot
+    pool's keys and values are replaced whole."""
+    def put(pool, row):
+        if pool.shape == row.shape:
+            return row
+        return lax.dynamic_update_slice_in_dim(
+            pool, row.astype(pool.dtype), slot, axis=1)
+    k = put(pool_cache["k"], row_cache["k"])
+    pos = pool_cache["pos"].at[slot].set(row_cache["pos"][0])
+    return {"k": k, "pos": pos, "v": put(pool_cache["v"], row_cache["v"])}
+
+
 def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
                 cache: Dict[str, Any]):
-    """One decode step.  tokens [B,1] → (logits [B,1,V], updated cache)."""
-    B = tokens.shape[0]
-    pos = cache["pos"]
-    x = L.embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
-    positions = jnp.broadcast_to(pos[None, None], (B, 1))
-    if cfg.mrope_sections:
-        positions = jnp.broadcast_to(positions[None], (3, B, 1))
+    """One decode step.  tokens [B,1] → (logits [B,1,V], updated cache).
 
-    def block(x, inp):
-        p, k_c, v_c = inp
-        h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
-        with jax.named_scope("attention"):
-            q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
-                             cfg.hd, cfg.qk_norm, cfg.norm_eps)
-            q = L.apply_rope(q, positions, cfg.rope_theta,
-                             cfg.mrope_sections, cfg.use_rope)
-            k = L.apply_rope(k, positions, cfg.rope_theta,
-                             cfg.mrope_sections, cfg.use_rope)
-            k_c = lax.dynamic_update_slice_in_dim(
-                k_c, k.astype(k_c.dtype), pos, axis=1)
-            v_c = lax.dynamic_update_slice_in_dim(
-                v_c, v.astype(v_c.dtype), pos, axis=1)
-            o = L.decode_attention(q, k_c, v_c, pos + 1)
-            o = o.reshape(B, 1, cfg.num_heads * cfg.hd) @ \
-                p["attn"]["wo"].astype(x.dtype)
-        x = x + o
-        h = L.rms_norm(p["ln2"], x, cfg.norm_eps)
-        if "moe" in p:
-            m, _ = L.moe_layer(p["moe"], h, cfg)
-        else:
-            m = L.mlp(p["mlp"], h, cfg.act)
-        return x + m, (k_c, v_c)
-
-    x, (k_new, v_new) = lax.scan(
-        block, x, (params["blocks"], cache["k"], cache["v"]))
-    x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
-    out = L.unembed(unembed_table(params), x, jnp.dtype(cfg.logits_dtype))
-    cache = {"k": k_new, "v": v_new, "pos": pos + 1}
-    return out, cache
-
-
-def decode_step_ragged(cfg: ModelConfig, params: Params, tokens: jax.Array,
-                       cache: Dict[str, Any]):
-    """Decode with PER-ROW positions — the continuous-batching path.
-
-    ``cache['pos']`` is [B]: each slot writes its k/v at its own offset
-    (scatter) and masks to its own prefix.  Used by the serve engine where
-    slots hold requests admitted at different times; the uniform-batch
-    ``decode_step`` remains the production multi-pod path (per-row scatter
-    onto a sequence-sharded cache would defeat the cache sharding).
-
-    The stacked caches ride the layer scan's carry and each layer scatters
-    its new keys and values into them in place (as the scan's sliced input
-    and stacked output instead, every layer's slab would be written back
-    whole each step).  The serve engine donates the cache, so nothing
-    copies it.
+    ``cache['pos']`` is a scalar, every row at one position, or ``[B]``,
+    each row at its own: the serve engine's slots hold requests admitted
+    at different times.  The stacked caches ride the layer scan's carry,
+    and each layer writes its new keys and values into them in place (as
+    the scan's sliced input and stacked output instead, every layer's slab
+    would be written back whole each step).  The serve engine donates the
+    cache, so nothing copies it.
     """
     B = tokens.shape[0]
-    pos = cache["pos"]                                   # [B]
-    bidx = jnp.arange(B)
+    pos = cache["pos"]
+    rows = L.kv_rows(pos, B)
     x = L.embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
-    positions = pos[:, None]
-    if cfg.mrope_sections:
-        positions = jnp.broadcast_to(positions[None], (3, B, 1))
+    positions = L.decode_positions(pos, B, cfg.mrope_sections)
 
     def block(carry, inp):
-        x, k_all, v_all = carry
+        x, kv = carry
         p, i = inp
-        h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
-        with jax.named_scope("attention"):
-            q, k, v = L._qkv(p["attn"], h, cfg.num_heads, cfg.num_kv_heads,
-                             cfg.hd, cfg.qk_norm, cfg.norm_eps)
-            q = L.apply_rope(q, positions, cfg.rope_theta,
-                             cfg.mrope_sections, cfg.use_rope)
-            k = L.apply_rope(k, positions, cfg.rope_theta,
-                             cfg.mrope_sections, cfg.use_rope)
-            k_all = k_all.at[i, bidx, pos].set(k[:, 0].astype(k_all.dtype))
-            v_all = v_all.at[i, bidx, pos].set(v[:, 0].astype(v_all.dtype))
-            o = L.decode_attention(q, k_all[i], v_all[i], pos + 1)
-            o = o.reshape(B, 1, cfg.num_heads * cfg.hd) @ \
-                p["attn"]["wo"].astype(x.dtype)
-        x = x + o
-        h = L.rms_norm(p["ln2"], x, cfg.norm_eps)
-        if "moe" in p:
-            m, _ = L.moe_layer(p["moe"], h, cfg)
-        else:
-            m = L.mlp(p["mlp"], h, cfg.act)
-        return (x + m, k_all, v_all), None
+        x, _, kv = _block(cfg, p, x, lambda pa, h: L.attention_decode(
+            pa, h, kv, i, pos, rows, positions, cfg))
+        return (x, kv), None
 
-    (x, k_new, v_new), _ = lax.scan(
-        block, (x, cache["k"], cache["v"]),
-        (params["blocks"], jnp.arange(cache["k"].shape[0])))
+    (x, (k, v)), _ = lax.scan(block, (x, (cache["k"], cache["v"])),
+                              (params["blocks"], jnp.arange(cfg.num_layers)))
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     out = L.unembed(unembed_table(params), x, jnp.dtype(cfg.logits_dtype))
-    return out, {"k": k_new, "v": v_new, "pos": pos + 1}
+    return out, {"k": k, "v": v, "pos": pos + 1}

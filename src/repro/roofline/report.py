@@ -1,4 +1,4 @@
-"""Markdown report generation from dry-run cell JSONs (EXPERIMENTS.md feed).
+"""Markdown report generation from dry-run cell JSONs.
 
 ``python -m repro.roofline.report [--dir results/dryrun] [--mesh pod16x16]``
 """

@@ -64,7 +64,6 @@ class ServeConfig:
     max_len: int = 512
     prompt_buckets: Tuple[int, ...] = (32, 64, 128, 256)
     cache_dtype: Any = jnp.bfloat16
-    greedy: bool = True
     # fence (block_until_ready) decoded tokens before stamping
     # first_token_at/done_at, so TTFT/latency measure *delivery*.
     # False reverts to stamping at dispatch-return — enqueue time, the
@@ -135,17 +134,19 @@ class ServeEngine:
         self.cache["pos"] = jnp.zeros((cfg.max_batch,), jnp.int32)
 
         def decode_step(p, t, c):
-            return transformer.decode_step_ragged(api.cfg, p, t, c)
+            return transformer.decode_step(api.cfg, p, t, c)
         # a named function: the trace calls the program jit_decode_step.
         # The pool cache is donated to the decode step and to the splice,
         # which update it in place: the engine holds no other reference
         self._decode = jax.jit(decode_step, donate_argnums=(2,))
-        self._splice = jax.jit(_splice_row, donate_argnums=(0,))
+        self._splice = jax.jit(transformer.write_slot, donate_argnums=(0,))
         self._prefill_cache = {}
         # host-side per-slot position clocks (prefix + decoded tokens):
         # max_len exhaustion is a host decision, it must not force the
         # device cache
         self._slot_pos = [0] * cfg.max_batch
+        # each slot's last token, the next decode step's input
+        self._pending_tok = np.zeros(cfg.max_batch, np.int32)
         #: one record per step(), the newest MAX_STEP_RECORDS
         self.step_records: Deque[StepRecord] = collections.deque(
             maxlen=MAX_STEP_RECORDS)
@@ -266,8 +267,6 @@ class ServeEngine:
         with span("engine.splice", spans):
             self.cache = self._splice(self.cache, row_cache, slot)
         self._slot_pos[slot] = n
-        self._pending_tok = getattr(self, "_pending_tok",
-                                    np.zeros(self.cfg.max_batch, np.int32))
         self._pending_tok[slot] = tok
 
     def _decode_step(self) -> List[Request]:
@@ -335,38 +334,3 @@ class ServeEngine:
                 "latency_mean_s": float(np.mean(lat)) if lat else 0.0,
                 "throughput_tok_s": toks / span if span > 0 else 0.0}
 
-
-def _splice_row(pool_cache, row_cache, slot):
-    """Copy a 1-row cache into slot ``slot`` (an int or a traced scalar:
-    one compiled splice serves every slot) of the pool cache.
-
-    Batch dim differs by cache kind: [L,B,...] arrays have it at axis 1,
-    hybrid ssm entries at axis 2; 'pos' is a scalar (shared clock — per
-    slot masking uses each row's own written prefix, padded rows attend to
-    zeros which are masked by cache_len; the engine keeps one global pos =
-    max over slots, acceptable because shorter slots' tails are zero-value
-    keys with near-zero attention mass... see tests/test_serve.py for the
-    correctness check).
-    """
-    def splice(pool, row):
-        if pool.ndim == 0:                     # scalar pos (unused here)
-            return jnp.maximum(pool, row)
-        if pool.ndim == 1 and row.ndim == 1 and row.shape[0] == 1:
-            return pool.at[slot].set(row[0])   # per-slot pos vector
-        if pool.ndim == 1 and row.ndim == 0:
-            return pool.at[slot].set(row)
-        if pool.shape == row.shape:
-            # max_batch == 1: the pool IS one row, there is no axis to
-            # search for (the size-1 batch dim matches everywhere) —
-            # without this case a single-slot engine silently drops the
-            # prefilled cache and decodes over zeros
-            return row
-        if pool.shape[0] != row.shape[0]:      # stacked-first? not expected
-            return pool
-        # find the batch axis: first axis where sizes differ
-        for ax in range(1, pool.ndim):
-            if row.shape[ax] == 1 and pool.shape[ax] > 1:
-                return jax.lax.dynamic_update_slice_in_dim(
-                    pool, row.astype(pool.dtype), slot, axis=ax)
-        return pool
-    return jax.tree_util.tree_map(splice, pool_cache, row_cache)

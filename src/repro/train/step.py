@@ -48,7 +48,7 @@ def make_train_step(api: ModelApi, opt_cfg: AdamWConfig,
     (observed 507 GB/device/step on jamba train_4k, 16 microbatches).
     Zero-sharded (ZeRO-style) accumulation turns each microbatch's sync
     into a reduce-scatter at 1/|data| the bytes — ~16x less gradient
-    traffic (EXPERIMENTS.md §Perf C3)."""
+    traffic."""
     schedule = warmup_cosine(opt_cfg)
 
     def loss_fn(params, batch):

@@ -1,4 +1,6 @@
 """Per-arch smoke tests (reduced configs) + serving-consistency checks."""
+import glob
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -74,6 +76,100 @@ def test_decode_matches_teacher_forcing(arch):
                                atol=5e-2, rtol=1e-2)
 
 
+@pytest.mark.parametrize("arch", ["internlm2-1.8b", "deepseek-moe-16b"])
+def test_decode_per_row_pos_matches_scalar_pos(arch):
+    """A [B] ``pos`` whose rows are all equal writes and reads the cache as
+    the scalar ``pos`` does: the per-row scatter and the all-rows
+    ``dynamic_update_slice`` of ``write_kv`` give the same logits and
+    caches."""
+    from repro.models import transformer
+    cfg = get_config(arch).reduced().override(moe_capacity_factor=8.0)
+    api = build(cfg)
+    params = api.init(jax.random.PRNGKey(1))
+    B, S = 2, 12
+    batch = make_batch(cfg, B, S, jax.random.PRNGKey(2))
+    _, cache = jax.jit(api.prefill)(params, batch, api.init_cache(B, S + 4))
+    tokens = batch["tokens"][:, -1:]
+    step = jax.jit(lambda c: transformer.decode_step(cfg, params, tokens, c))
+    ls, cs = step(cache)
+    lr, cr = step(dict(cache, pos=jnp.full((B,), S, jnp.int32)))
+    assert cs["pos"].shape == () and cr["pos"].shape == (B,)
+    np.testing.assert_array_equal(np.asarray(cr["pos"]), S + 1)
+    np.testing.assert_allclose(np.asarray(lr, np.float32),
+                               np.asarray(ls, np.float32), rtol=0, atol=0)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(cr[name], np.float32),
+                                      np.asarray(cs[name], np.float32))
+
+
+@pytest.mark.parametrize("slot", [0, 1, 2])
+def test_write_slot_changes_only_that_slot(slot):
+    """A prefill's one-row cache lands in slot ``slot`` of a 3-slot pool:
+    that slot's keys, values and position are the row's, and every other
+    slot is as it was."""
+    from repro.models import transformer
+    Ln, B, S, K, hd = 2, 3, 8, 2, 4
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    pool = {"k": jax.random.normal(ks[0], (Ln, B, S, K, hd), jnp.bfloat16),
+            "v": jax.random.normal(ks[1], (Ln, B, S, K, hd), jnp.bfloat16),
+            "pos": jnp.asarray([5, 6, 7], jnp.int32)}
+    row = {"k": jax.random.normal(ks[2], (Ln, 1, S, K, hd), jnp.bfloat16),
+           "v": jax.random.normal(ks[3], (Ln, 1, S, K, hd), jnp.bfloat16),
+           "pos": jnp.asarray([3], jnp.int32)}
+    want = {name: np.array(pool[name]) for name in pool}
+    out = jax.jit(transformer.write_slot)(pool, row, slot)
+    for name in ("k", "v"):
+        want[name][:, slot] = np.asarray(row[name])[:, 0]
+    want["pos"][slot] = 3
+    assert sorted(out) == sorted(want)
+    for name, a in out.items():
+        assert a.dtype == pool[name].dtype
+        np.testing.assert_array_equal(np.asarray(a), want[name])
+
+
+def test_attention_sublayer_is_written_once():
+    """Outside ``layers.py`` no model family projects q/k/v, applies rope
+    or calls an attention kernel itself: they all go through
+    ``layers.attention`` and ``layers.attention_decode``, the one place
+    that reads ``attn_impl``.  encdec's cross-attention, the only one of
+    its kind, calls its kernels on its own (query ``qx``)."""
+    import ast
+    import os
+    from repro import models
+    sublayer = {"_qkv", "apply_rope", "naive_attention",
+                "flash_attention_xla"}
+    calls, impl_reads = [], []
+    for path in sorted(glob.glob(os.path.join(
+            os.path.dirname(models.__file__), "*.py"))):
+        name = os.path.basename(path)
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for fn in ast.walk(tree):
+            if not isinstance(fn, ast.FunctionDef):
+                continue
+            for node in ast.walk(fn):
+                if isinstance(node, ast.Attribute) \
+                        and node.attr == "attn_impl":
+                    impl_reads.append((name, fn.name))
+        if name == "layers.py":
+            continue
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.Call):
+                continue
+            f = node.func
+            callee = f.attr if isinstance(f, ast.Attribute) else \
+                getattr(f, "id", None)
+            if callee not in sublayer:
+                continue
+            first = node.args[0] if node.args else None
+            cross = (name == "encdec.py" and isinstance(first, ast.Name)
+                     and first.id == "qx")
+            if not cross:
+                calls.append((name, node.lineno, callee))
+    assert calls == []
+    assert sorted(set(impl_reads)) == [("layers.py", "attention")]
+
+
 def _jaxpr_shapes(jaxpr):
     """Shapes of every intermediate of a jaxpr, scan and jit bodies too."""
     for eqn in jaxpr.eqns:
@@ -110,7 +206,7 @@ def test_ragged_decode_reads_gqa_cache_once_per_kv_head(arch, kv_heads):
     tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32)
 
     def step(p, t, c):
-        return transformer.decode_step_ragged(cfg, p, t, c)
+        return transformer.decode_step(cfg, p, t, c)
 
     jaxpr = jax.make_jaxpr(step)(params, tokens, cache).jaxpr
     shapes = set(_jaxpr_shapes(jaxpr))

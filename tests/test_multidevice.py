@@ -138,7 +138,7 @@ def test_decode_gqa_path_follows_the_head_rules():
         tokens = jax.random.randint(ks[2], (B, 1), 0, cfg.vocab_size)
 
         def step(p, t, c):
-            return transformer.decode_step_ragged(cfg, p, t, c)
+            return transformer.decode_step(cfg, p, t, c)
 
         ref, _ = jax.jit(step)(params, tokens, cache)
         for model, scope in ((4, "gqa_repeated"), (2, "gqa_grouped")):

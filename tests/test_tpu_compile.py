@@ -263,7 +263,7 @@ def test_decode_step_reads_the_kv_cache_as_stored(one_chip):
     (batch, kv head)."""
     cfg, args = _served_decode_args(one_chip)
     text = _compile_text(
-        lambda p, t, c: transformer.decode_step_ragged(cfg, p, t, c), *args)
+        lambda p, t, c: transformer.decode_step(cfg, p, t, c), *args)
     layer_cache = _SLOTS * _MAX_LEN * cfg.num_kv_heads * cfg.hd
     offending = []
     for line in text.splitlines():
@@ -287,7 +287,7 @@ def test_decode_step_updates_the_kv_cache_in_place(one_chip):
     does); the new tokens' keys and values are scattered in place."""
     cfg, args = _served_decode_args(one_chip)
     compiled = jax.jit(
-        lambda p, t, c: transformer.decode_step_ragged(cfg, p, t, c),
+        lambda p, t, c: transformer.decode_step(cfg, p, t, c),
         donate_argnums=(2,)).lower(*args).compile()
     stacked = cfg.num_layers * _SLOTS * _MAX_LEN * cfg.num_kv_heads * cfg.hd
     kv_bytes = 2 * stacked * jnp.dtype(jnp.bfloat16).itemsize
